@@ -1,9 +1,10 @@
-"""Kernel-level benchmark: oracle-vs-kernel agreement + CPU twin walltimes.
+"""Kernel-level benchmark: oracle-vs-kernel agreement + oracle walltimes.
 
-Interpret-mode Pallas timing is not meaningful (Python per-block execution);
-what we CAN measure on CPU is (a) correctness vs oracle across sizes, and
-(b) the jnp twin implementations' walltime scaling, which bounds the fused
-kernels' arithmetic. TPU-side numbers come from the dry-run roofline.
+The kernels resolve their mode by backend (``interpret=None``): compiled on
+a TPU, interpret mode everywhere else. Interpret-mode Pallas timing is not
+meaningful (Python per-block execution), so off the chip this suite checks
+agreement with the oracles and times only the jnp oracles and twins, on the
+device every row names. It is not a device benchmark.
 """
 from __future__ import annotations
 
@@ -31,7 +32,7 @@ def topk_agreement():
     for (N, d, Q, k) in [(2048, 64, 8, 10), (8192, 128, 4, 10)]:
         c = jnp.asarray(rng.normal(size=(N, d)).astype(np.float32))
         q = jnp.asarray(rng.normal(size=(Q, d)).astype(np.float32))
-        s, i = topk_distance(c, q, k=k, metric="dot", blk_n=512, interpret=True)
+        s, i = topk_distance(c, q, k=k, metric="dot", blk_n=512)
         rs, ri = R.topk_distance_ref(c, q, k=k, metric="dot")
         ok = bool((np.asarray(i) == np.asarray(ri)).all())
         oracle_t = _timeit(jax.jit(lambda c, q: R.topk_distance_ref(c, q, k=k)), c, q)
@@ -45,7 +46,7 @@ def pq_adc_agreement():
     for (N, m, ksub, Q, k) in [(4096, 8, 256, 8, 10), (8192, 16, 256, 4, 10)]:
         codes = jnp.asarray(rng.integers(0, ksub, (N, m)).astype(np.int32))
         luts = jnp.asarray(rng.normal(size=(Q, m, ksub)).astype(np.float32))
-        s, i = pq_adc(codes, luts, k=k, blk_n=512, interpret=True)
+        s, i = pq_adc(codes, luts, k=k, blk_n=512)
         rs, ri = R.pq_adc_ref(codes, luts, k=k)
         ok = bool((np.asarray(i) == np.asarray(ri)).all())
         oracle_t = _timeit(jax.jit(lambda c, l: R.pq_adc_ref(c, l, k=k)),
@@ -101,14 +102,11 @@ def ivf_adc_agreement():
 
 
 def ivf_adc_run_resident_agreement():
-    """Run-resident grid (PR 9): Pallas kernel (interpret) vs jnp twin vs
-    the gather oracle on the same visit table. The run-resident kernel
-    shares the blocked kernel's one-hot contraction, so those two grids
-    are bit-exact (scores AND ids) on any geometry; against the per-query
-    grid and across executors (kernel vs twin, twin vs oracle) ids must
-    agree while scores may differ in the last ulp when the reduction
-    reassociates (large m*ksub). Sizes stay small — interpret mode
-    executes per-run Python."""
+    """Run-resident grid: Pallas kernel vs jnp twin vs
+    the gather oracle on the same visit table. Every grid scores with the
+    same per-subspace contraction folded in the same order, so the grids
+    are bit-exact (scores AND ids) on any geometry. Sizes stay small —
+    interpret mode executes per-run Python."""
     from repro.core import build_block_lists
     from repro.kernels import ivf_adc_topk
 
@@ -134,11 +132,11 @@ def ivf_adc_run_resident_agreement():
         st, it = ivf_adc_topk(codes, slots, visit, luts, use_kernel=False,
                               mode="run_resident", **kw)
         sk, ik = ivf_adc_topk(codes, slots, visit, luts, use_kernel=True,
-                              interpret=True, mode="run_resident", **kw)
+                              mode="run_resident", **kw)
         sp, ip = ivf_adc_topk(codes, slots, visit, luts, use_kernel=True,
-                              interpret=True, mode="per_query", **kw)
+                              mode="per_query", **kw)
         sb, ib = ivf_adc_topk(codes, slots, visit, luts, use_kernel=True,
-                              interpret=True, mode="blocked", **kw)
+                              mode="blocked", **kw)
         rs, ri = R.ivf_adc_ref(codes, slots, visit, luts, k=k,
                                steps_per_probe=spp)
         twin_vs_oracle = bool((np.asarray(it) == np.asarray(ri)).all())
@@ -165,7 +163,7 @@ def hamming_agreement():
     for (T, Q, N, W) in [(4, 8, 4096, 4)]:
         qc = jnp.asarray(rng.integers(0, 2**32, (T, Q, W), dtype=np.uint64).astype(np.uint32))
         cc = jnp.asarray(rng.integers(0, 2**32, (T, N, W), dtype=np.uint64).astype(np.uint32))
-        out = hamming(qc, cc, blk_n=512, interpret=True)
+        out = hamming(qc, cc, blk_n=512)
         ref = R.hamming_ref(qc, cc)
         ok = bool((np.asarray(out) == np.asarray(ref)).all())
         oracle_t = _timeit(jax.jit(R.hamming_ref), qc, cc)
@@ -174,6 +172,9 @@ def hamming_agreement():
 
 
 def main(quick: bool = False):
+    dev = jax.devices()[0]
+    print(f"# kernels on {dev.platform} {dev.device_kind} "
+          f"(interpret mode: {dev.platform != 'tpu'})")
     print("name,case,match,oracle_s")
     rows = {"topk": topk_agreement(), "pq_adc": pq_adc_agreement(),
             "ivf_adc": ivf_adc_agreement(),

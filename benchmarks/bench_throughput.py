@@ -912,8 +912,9 @@ print(json.dumps({{
 def distributed_pq_memory(shards: int = 4, N: int = 4096, d: int = 64):
     """Per-device resident bytes of DistributedPQ vs the replicated f32
     corpus, on a forced {shards}-device host mesh (own process: jax pins the
-    device count at first init)."""
-    env = dict(os.environ,
+    device count at first init). The child is held to the CPU: it counts
+    bytes, and on a TPU host the parent already holds the chip."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=f"--xla_force_host_platform_device_count={shards}",
                PYTHONPATH="src")
     out = subprocess.run(

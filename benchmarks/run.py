@@ -27,6 +27,8 @@ def main() -> None:
     ap.add_argument("--json", default=None,
                     help="dump every suite's returned rows to this path")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import bench_index, bench_kernels, bench_serve, bench_throughput
     suites = {"index": bench_index.main, "throughput": bench_throughput.main,
               "serve": bench_serve.main, "kernels": bench_kernels.main}

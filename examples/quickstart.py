@@ -9,6 +9,7 @@ reproduce it, examples/serve_vectordb.py for the serving fronts.
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import VectorDB
 from repro.data import MarcoLike, simple_tokenizer
 
@@ -141,4 +142,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_arch
 from repro.core import VectorDB
 from repro.data import ClickLogs
@@ -66,4 +67,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

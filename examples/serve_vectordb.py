@@ -25,6 +25,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import DistributedVectorDB, VectorDB
 from repro.serve import AsyncQueryEngine, QueryEngine
 
@@ -153,4 +154,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.checkpoint import CheckpointStore
 from repro.configs import get_arch
 from repro.core import VectorDB
@@ -145,4 +146,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

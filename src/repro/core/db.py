@@ -51,12 +51,12 @@ from repro.core import distances as D
 from repro.core import distributed as dist
 from repro.core.flat import FlatIndex
 from repro.core.graph import GraphIndex
-from repro.core.ivf import (BlockListLayout, IVFIndex, ScheduleCache,
-                            assign_clusters, kmeans)
+from repro.core.ivf import BlockListLayout, IVFIndex, ScheduleCache
 from repro.core.lsh import LSHIndex
 from repro.core.mutable import MutationMixin
-from repro.core.pq import (IVFPQIndex, PQIndex, adc_tables, expand_visit,
-                           pq_encode, probe_luts, train_pq)
+from repro.core.pq import (IVFPQIndex, PQIndex, adc_tables, encode_ivf_pq,
+                           expand_visit, pq_encode, probe_luts, train_ivf_pq,
+                           train_pq)
 from repro.core.quant import Int8FlatIndex
 from repro.core.wal import WriteAheadLog
 from repro.ft.faults import crashpoint
@@ -240,9 +240,11 @@ class VectorDB(_PlanLedger, _WriteFront):
         0..N-1: either a columnar dict ({column: [v0..vN-1]}) or a list of
         per-row dicts — see ``repro.search.meta``. Load is not WAL-logged
         (it precedes durability), so its metadata rides snapshots only."""
-        vectors = jnp.asarray(vectors)
+        if not hasattr(vectors, "shape"):
+            vectors = np.asarray(vectors)
         assert vectors.ndim == 2, vectors.shape
-        self.index.load(vectors)
+        self.index.load(vectors)  # host rows stay on the host: the engine
+                                  # decides what goes to the device
         self.n = vectors.shape[0]
         self._loaded = True
         self.metastore = MetadataStore()  # fresh corpus, fresh id space
@@ -917,6 +919,10 @@ class DistributedIVFPQ(_PlanLedger, _WriteFront, MutationMixin):
     def size(self) -> int:
         return 0 if self.layout is None else int(self.layout.live)
 
+    @property
+    def _replicated(self):
+        return NamedSharding(self.mesh, P())
+
     def _alloc_policy(self, cluster: int, free_rows) -> int:
         """Spilled blocks land on the shard owning the cluster's slab (its
         last block's shard); a full shard falls back to the emptiest row."""
@@ -929,22 +935,21 @@ class DistributedIVFPQ(_PlanLedger, _WriteFront, MutationMixin):
         return min(same) if same else min(free_rows)
 
     def load(self, vectors) -> "DistributedIVFPQ":
-        x = jnp.asarray(vectors, jnp.float32)
-        self.n, self.d = x.shape
+        """Index raw rows — host, one device, or (at scale) already
+        row-sharded over the mesh: the quantizers train on a sample and
+        the rows stream through the encoder in chunks, so no device ever
+        holds the whole corpus; the block slabs then shard by row range."""
+        self.n, self.d = vectors.shape
         C = self.n_clusters or max(1, int(np.sqrt(self.n)))
         C = min(C, self.n)
-        corpus, _sq = D.preprocess_corpus(x, self.metric)
-        key = jax.random.PRNGKey(self.seed)
-        cent = kmeans(key, corpus, n_clusters=C, iters=self.kmeans_iters)
-        if self.metric == "cosine":
-            cent = D.l2_normalize(cent)
-        assign = np.asarray(assign_clusters(corpus, cent))
-        residuals = corpus - jnp.take(cent, jnp.asarray(assign), axis=0)
-        self.codebooks = train_pq(jax.random.fold_in(key, 1), residuals,
-                                  m=self.m, ksub=self.ksub,
-                                  iters=self.kmeans_iters)
-        codes = np.asarray(pq_encode(self.codebooks, residuals))
-        self.centroids = cent
+        cent, codebooks = train_ivf_pq(
+            vectors, metric=self.metric, n_clusters=C, m=self.m,
+            ksub=self.ksub, iters=self.kmeans_iters, seed=self.seed)
+        _, _, assign, codes = encode_ivf_pq(
+            codebooks, cent, vectors, metric=self.metric, keep_rows=False)
+        # the coarse structures replicate over the mesh, next to the slabs
+        self.centroids = jax.device_put(cent, self._replicated)
+        self.codebooks = jax.device_put(codebooks, self._replicated)
         # storage rows stay a multiple of the shard count so they slice into
         # equal per-shard slabs; the policy steers spills to the owner shard
         self.layout = BlockListLayout.from_assign(
@@ -956,12 +961,10 @@ class DistributedIVFPQ(_PlanLedger, _WriteFront, MutationMixin):
 
     # ---------------------------------------------------------- mutation
     def _encode_batch(self, vectors):
-        x = jnp.atleast_2d(jnp.asarray(vectors, jnp.float32))
-        rows, _sq = D.preprocess_corpus(x, self.metric)
-        assign = np.asarray(assign_clusters(rows, self.centroids))
-        residuals = rows - jnp.take(self.centroids, jnp.asarray(assign),
-                                    axis=0)
-        return np.asarray(pq_encode(self.codebooks, residuals)), assign
+        _, _, assign, codes = encode_ivf_pq(
+            self.codebooks, self.centroids, np.atleast_2d(vectors),
+            metric=self.metric, keep_rows=False)
+        return codes, assign
 
     def _after_mutation(self, shape_before) -> None:
         if self.layout.shape_key != shape_before:
@@ -1019,7 +1022,7 @@ class DistributedIVFPQ(_PlanLedger, _WriteFront, MutationMixin):
         sharding = dist.corpus_sharding(self.mesh, self.axes)
         self.bucket_ids = jax.device_put(jnp.asarray(slots_sharded), sharding)
         self.codes_bm = jax.device_put(jnp.asarray(codes_sharded), sharding)
-        self.block_table = jnp.asarray(lay.block_table)
+        self.block_table = jax.device_put(lay.block_table, self._replicated)
         self.spp = lay.steps_per_probe
         self.blocks_per_shard = bloc
         self._dirty = False

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import distances as D
 from repro.core.flat import flat_search
 from repro.kernels import ops as kops
@@ -100,8 +99,8 @@ def sharded_flat_search(corpus, q, *, mesh: Mesh, k: int, metric: str = "cosine"
                                  hierarchical=hierarchical)
 
     args = (corpus, q) + ((valid,) if valid is not None else ())
-    return shard_map(local_search, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_replication=False)(*args)
+    return jax.shard_map(local_search, mesh=mesh, in_specs=in_specs,
+                     out_specs=out_specs, check_vma=False)(*args)
 
 
 def sharded_pq_search(codes, luts, *, mesh: Mesh, k: int, axes=None,
@@ -144,8 +143,8 @@ def sharded_pq_search(codes, luts, *, mesh: Mesh, k: int, axes=None,
                                  hierarchical=hierarchical)
 
     args = (codes, luts) + ((valid,) if valid is not None else ())
-    return shard_map(local_search, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_replication=False)(*args)
+    return jax.shard_map(local_search, mesh=mesh, in_specs=in_specs,
+                     out_specs=out_specs, check_vma=False)(*args)
 
 
 def sharded_ivf_pq_search(bucket_codes, bucket_ids, visit, luts, coarse, *,
@@ -202,8 +201,8 @@ def sharded_ivf_pq_search(bucket_codes, bucket_ids, visit, luts, coarse, *,
         return _merge_local_topk(s, i, k=k, axes=axes,
                                  hierarchical=hierarchical)
 
-    return shard_map(local_search, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_replication=False)(
+    return jax.shard_map(local_search, mesh=mesh, in_specs=in_specs,
+                     out_specs=out_specs, check_vma=False)(
                          bucket_codes, bucket_ids, visit, luts, coarse)
 
 
@@ -258,11 +257,11 @@ def two_level_search(corpus, q, *, mesh: Mesh, k: int, q_axes, c_axes,
         s, pos = jax.lax.top_k(s_all, k)
         return s, jnp.take_along_axis(i_all, pos, axis=-1)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(c_axes, None), P(q_axes, None)),
         out_specs=(P(q_axes, None), P(q_axes, None)),
-        check_replication=False)(corpus, q)
+        check_vma=False)(corpus, q)
 
 
 @functools.partial(jax.jit, static_argnames=("k",))

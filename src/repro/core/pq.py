@@ -64,25 +64,63 @@ def subspace_split(x, m: int):
     return x.reshape(N, m, dsub)
 
 
+# k-means (coarse and per-subspace) trains on at most this many rows; a
+# larger corpus trains on a sample drawn from the seed, so training memory
+# and time stay flat in N (FAISS caps training points per centroid alike)
+MAX_TRAIN_ROWS = 1 << 18
+# rows per encode step: bounds the (rows, m, ksub) nearest-centroid scores
+# (200 MB at m=96, ksub=256) and the (rows, C) coarse scores
+ENCODE_ROWS = 2048
+
+
+def device_rows(x, start: int, stop: int):
+    """Rows [start, stop) of a host array, a device array, or an array
+    sharded over several devices, as f32 on one device."""
+    part = x[start:stop]
+    if isinstance(part, jax.Array) and len(part.sharding.device_set) > 1:
+        part = jax.device_put(part, jax.devices()[0])
+    return jnp.asarray(part, jnp.float32)
+
+
+def training_sample(key, x, n_max: int = MAX_TRAIN_ROWS):
+    """The rows k-means trains on, as f32 on one device: all of ``x`` when
+    it has at most ``n_max`` rows, else ``n_max`` rows drawn without
+    replacement (kept in corpus order)."""
+    N = x.shape[0]
+    if N <= n_max:
+        return device_rows(x, 0, N)
+    idx = np.sort(np.asarray(jax.random.choice(key, N, (n_max,),
+                                               replace=False)))
+    if isinstance(x, jax.Array):
+        idx = jnp.asarray(idx)
+    return device_rows(x[idx], 0, n_max)
+
+
 def train_pq(key, x, *, m: int, ksub: int = 256, iters: int = 10):
     """Per-subspace Lloyd k-means. x: (N, d) f32 -> codebooks (m, ksub, dsub).
 
     Zero-padded tail dims train like real dims (their centroids are ~0, so
     they cannot change any ranking). ksub caps at N and 256 (codes are u8).
+    Subspaces are column slices of the 2-D rows: a (N, m, dsub) view would
+    pad its dsub-wide minor axis to the TPU's 128 lanes.
     """
     assert ksub <= 256, "codes are stored as uint8"
     ksub = min(ksub, x.shape[0])
-    xs = subspace_split(jnp.asarray(x, jnp.float32), m)
+    x = jnp.asarray(x, jnp.float32)
+    d = x.shape[1]
+    dsub = -(-d // m)
+    if m * dsub > d:
+        x = jnp.pad(x, ((0, 0), (0, m * dsub - d)))
     keys = jax.random.split(key, m)
     return jnp.stack([
-        kmeans(keys[j], xs[:, j, :], n_clusters=ksub, iters=iters)
+        kmeans(keys[j], x[:, j * dsub:(j + 1) * dsub], n_clusters=ksub,
+               iters=iters)
         for j in range(m)
     ])
 
 
 @jax.jit
-def pq_encode(codebooks, x):
-    """x: (N, d) -> codes (N, m) uint8 (nearest centroid per subspace)."""
+def _pq_encode_rows(codebooks, x):
     m = codebooks.shape[0]
     xs = subspace_split(jnp.asarray(x, jnp.float32), m)  # (N, m, dsub)
     dots = jnp.einsum("nmd,mkd->nmk", xs, codebooks,
@@ -90,6 +128,27 @@ def pq_encode(codebooks, x):
     c_sq = jnp.sum(jnp.square(codebooks), axis=-1)  # (m, ksub)
     # argmin ||x - c||^2 == argmax 2 x.c - |c|^2 (|x|^2 constant per row)
     return jnp.argmax(2.0 * dots - c_sq[None], axis=-1).astype(jnp.uint8)
+
+
+def _chunked(fn, x, *args):
+    """Apply a row-wise jitted ``fn(*args, rows)`` over ``x`` in
+    ENCODE_ROWS chunks (the last zero-padded, so one executable serves
+    all) and yield each chunk's outputs trimmed to its real rows."""
+    N = x.shape[0]
+    for start in range(0, N, ENCODE_ROWS):
+        stop = min(start + ENCODE_ROWS, N)
+        rows = device_rows(x, start, stop)
+        if N > ENCODE_ROWS and stop - start < ENCODE_ROWS:
+            rows = jnp.pad(rows, ((0, ENCODE_ROWS - (stop - start)), (0, 0)))
+        out = fn(*args, rows)
+        yield jax.tree.map(lambda a: a[:stop - start], out)
+
+
+def pq_encode(codebooks, x):
+    """x: (N, d) -> codes (N, m) uint8 (nearest centroid per subspace),
+    encoded ENCODE_ROWS rows at a time."""
+    parts = list(_chunked(_pq_encode_rows, x, codebooks))
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
 
 @functools.partial(jax.jit, static_argnames=("d",))
@@ -176,8 +235,11 @@ def _exact_rerank(corpus, corpus_sq, cand, q, *, metric: str, k: int):
     valid = cand >= 0
     safe = jnp.where(valid, cand, 0)
     vecs = jnp.take(corpus, safe, axis=0)  # (Q, R, d)
+    # exact means f32: the chip's default matmul precision would round both
+    # operands to bf16 and reorder near-ties the re-rank exists to settle
     dots = jnp.einsum("qd,qrd->qr", q.astype(jnp.float32),
-                      vecs.astype(jnp.float32), preferred_element_type=jnp.float32)
+                      vecs.astype(jnp.float32), preferred_element_type=jnp.float32,
+                      precision=jax.lax.Precision.HIGHEST)
     if metric == "dot":
         scores = dots
     else:
@@ -474,6 +536,55 @@ def ivf_pq_search(codebooks, codes, centroids, buckets, corpus, q, *,
     if refine:
         return _exact_rerank(corpus, corpus_sq, ids, q, metric=metric, k=k)
     return _pad_to_k(s[:, :k], ids[:, :k], k)
+
+
+def train_ivf_pq(x, *, metric: str, n_clusters: int, m: int, ksub: int,
+                 iters: int, seed: int):
+    """Train the IVF-PQ quantizers on (a sample of) the raw rows ``x`` ->
+    (centroids (C, d), codebooks (m, ksub, dsub)): coarse k-means in the
+    search geometry (cosine clusters unit vectors), then PQ on the
+    sample's residuals. Shared by ``IVFPQIndex`` and the sharded front."""
+    key = jax.random.PRNGKey(seed)
+    corpus, _ = D.preprocess_corpus(
+        training_sample(jax.random.fold_in(key, 2), x), metric)
+    cent = kmeans(key, corpus, n_clusters=n_clusters, iters=iters)
+    if metric == "cosine":
+        cent = D.l2_normalize(cent)
+    residuals = corpus - jnp.take(cent, assign_clusters(corpus, cent), axis=0)
+    codebooks = train_pq(jax.random.fold_in(key, 1), residuals, m=m,
+                         ksub=ksub, iters=iters)
+    return cent, codebooks
+
+
+@functools.partial(jax.jit, static_argnames=("metric",))
+def _ivf_pq_encode_rows(codebooks, centroids, x, *, metric: str):
+    rows, sq = D.preprocess_corpus(x, metric)
+    assign = assign_clusters(rows, centroids)
+    residuals = rows - jnp.take(centroids, assign, axis=0)
+    return rows, sq, assign.astype(jnp.int32), _pq_encode_rows(codebooks,
+                                                               residuals)
+
+
+def encode_ivf_pq(codebooks, centroids, x, *, metric: str,
+                  keep_rows: bool):
+    """Stream raw rows ``x`` (host, device, or sharded) through the IVF-PQ
+    encoder ENCODE_ROWS at a time -> host (rows or None, sq or None,
+    assign (N,) int32, codes (N, m) uint8), where rows are the
+    preprocessed (cosine: normalized) vectors the exact re-rank reads and
+    sq their squared norms (l2 only). Device memory stays one chunk deep,
+    so a corpus larger than one device's memory encodes too."""
+    parts = {"rows": [], "sq": [], "assign": [], "codes": []}
+    for rows, sq, assign, codes in _chunked(
+            functools.partial(_ivf_pq_encode_rows, metric=metric), x,
+            codebooks, centroids):
+        if keep_rows:
+            parts["rows"].append(np.asarray(rows))
+        if sq is not None:
+            parts["sq"].append(np.asarray(sq))
+        parts["assign"].append(np.asarray(assign))
+        parts["codes"].append(np.asarray(codes))
+    return tuple(np.concatenate(parts[key]) if parts[key] else None
+                 for key in ("rows", "sq", "assign", "codes"))
 
 
 def _check_snapshot(state, engine: str, metric: str):
@@ -801,38 +912,30 @@ class IVFPQIndex(MutationMixin):
         self._sync()  # device mirrors valid immediately after load/restore
 
     def load(self, vectors):
-        x = jnp.asarray(vectors, jnp.float32)
-        N, self.d = x.shape
+        """Index raw rows (host, device, or sharded): train the quantizers
+        on a sample, then encode in chunks — the full corpus is never
+        resident on the device here, only the re-rank copy ``_sync``
+        uploads when ``refine`` > 0."""
+        N, self.d = vectors.shape
         C = self.n_clusters or max(1, int(np.sqrt(N)))
         C = min(C, N)
-        corpus, sq = D.preprocess_corpus(x, self.metric)
-        key = jax.random.PRNGKey(self.seed)
-        cent = kmeans(key, corpus, n_clusters=C, iters=self.kmeans_iters)
-        if self.metric == "cosine":
-            cent = D.l2_normalize(cent)
-        assign = np.asarray(assign_clusters(corpus, cent))
-        residuals = corpus - jnp.take(cent, jnp.asarray(assign), axis=0)
-        self.codebooks = train_pq(jax.random.fold_in(key, 1), residuals,
-                                  m=self.m, ksub=self.ksub,
-                                  iters=self.kmeans_iters)
-        self.centroids = cent
-        self._corpus = (GrowableRows.from_array(np.asarray(corpus))
-                        if self.refine else None)
-        self._sq = (GrowableRows.from_array(np.asarray(sq))
-                    if sq is not None else None)
-        self._finalize_layout(pq_encode(self.codebooks, residuals), assign)
+        self.centroids, self.codebooks = train_ivf_pq(
+            vectors, metric=self.metric, n_clusters=C, m=self.m,
+            ksub=self.ksub, iters=self.kmeans_iters, seed=self.seed)
+        rows, sq, assign, codes = encode_ivf_pq(
+            self.codebooks, self.centroids, vectors, metric=self.metric,
+            keep_rows=bool(self.refine))
+        self._corpus = GrowableRows.from_array(rows) if self.refine else None
+        self._sq = GrowableRows.from_array(sq) if sq is not None else None
+        self._finalize_layout(codes, assign)
         return self
 
     # ---------------------------------------------------------- mutation
     def _encode_batch(self, vectors):
-        x = jnp.atleast_2d(jnp.asarray(vectors, jnp.float32))
-        rows, sq = D.preprocess_corpus(x, self.metric)
-        assign = np.asarray(assign_clusters(rows, self.centroids))
-        residuals = rows - jnp.take(self.centroids, jnp.asarray(assign),
-                                    axis=0)
-        codes = np.asarray(pq_encode(self.codebooks, residuals))
-        return codes, assign, np.asarray(rows), \
-            None if sq is None else np.asarray(sq)
+        rows, sq, assign, codes = encode_ivf_pq(
+            self.codebooks, self.centroids, np.atleast_2d(vectors),
+            metric=self.metric, keep_rows=True)
+        return codes, assign, rows, sq
 
     def _write_side(self, ids, assign, codes, rows, sq) -> None:
         self._write_mirrors(ids, ((self._corpus, rows), (self._sq, sq),

@@ -89,12 +89,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pq_adc import quantize_lut_int8
+from repro.kernels.pq_adc import adc_block_scores, quantize_lut_int8
 from repro.kernels.topk_distance import NEG_INF, _select_topk
 
 
+def _probe_coarse(crow, p):
+    """(1, nprobe) coarse row -> (1, 1) entry p, selected by a lane mask
+    (exact: one nonzero term)."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, crow.shape, 1)
+    return jnp.sum(jnp.where(lane == p, crow, 0.0), axis=1, keepdims=True)
+
+
 def _ivf_adc_kernel(visit_ref, c_ref, id_ref, l_ref, coarse_ref, *refs,
-                    n_steps: int, k: int, ksub: int, int8: bool):
+                    n_steps: int, spp: int, k: int, ksub: int, int8: bool):
     if int8:
         sc_ref, s_out, i_out, bs_ref, bi_ref = refs
     else:
@@ -107,31 +114,12 @@ def _ivf_adc_kernel(visit_ref, c_ref, id_ref, l_ref, coarse_ref, *refs,
         bs_ref[...] = jnp.full_like(bs_ref, NEG_INF)
         bi_ref[...] = jnp.full_like(bi_ref, -1)
 
-    codes = c_ref[...][0]  # (blk, m) int32 — the visited block's codes
-    ids = id_ref[...]      # (1, blk) int32 global row ids, -1 = pad slot
-    blk, m = codes.shape
-    # one-hot selector: the LUT gather as an MXU contraction (see pq_adc)
-    sub = jax.lax.broadcasted_iota(jnp.int32, (blk, m, ksub), 2)
-    sel = codes[:, :, None] == sub
-    lut = l_ref[...].reshape(1, m * ksub)
-    if int8:
-        # m int8 x int8 -> int32 sub-contractions (exact), scaled+summed f32
-        scale = sc_ref[...].reshape(1, m)
-        sel8 = sel.astype(jnp.int8)
-        s = None
-        for j in range(m):
-            pj = jax.lax.dot_general(
-                lut[:, j * ksub:(j + 1) * ksub], sel8[:, j, :],
-                (((1,), (1,)), ((), ())), preferred_element_type=jnp.int32)
-            pj = pj.astype(jnp.float32) * scale[:, j][:, None]
-            s = pj if s is None else s + pj
-    else:
-        sel_f = sel.astype(lut.dtype).reshape(blk, m * ksub)
-        s = jax.lax.dot_general(lut, sel_f, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)  # (1, blk)
+    ids = id_ref[0]        # (1, blk) int32 global row ids, -1 = pad slot
+    s = adc_block_scores(l_ref[0], c_ref[0], ksub,
+                         None if sc_ref is None else sc_ref[0])  # (1, blk)
     # coarse carries the metric's centroid term AND the caller's probe
     # knockout (NEG_INF for masked probes); pad slots knock out on id
-    s = s + coarse_ref[...]
+    s = s + _probe_coarse(coarse_ref[0], t // spp)
     s = jnp.where(ids >= 0, s, NEG_INF)
 
     comb_s = jnp.concatenate([bs_ref[...], s], axis=1)
@@ -140,8 +128,23 @@ def _ivf_adc_kernel(visit_ref, c_ref, id_ref, l_ref, coarse_ref, *refs,
 
     @pl.when(t == n_steps - 1)
     def _finalize():
-        s_out[...] = bs_ref[...]
-        i_out[...] = bi_ref[...]
+        s_out[0] = bs_ref[...]
+        i_out[0] = bi_ref[...]
+
+
+def _prep_luts(luts, lut_dtype: str):
+    """Cast / quantize the tables once per call -> (luts, scales or None)."""
+    if lut_dtype == "int8":
+        return quantize_lut_int8(luts)
+    if jnp.dtype(lut_dtype) != jnp.float32:
+        return luts.astype(jnp.dtype(lut_dtype)), None
+    return luts, None
+
+
+# scalar memory the per-query grid's prefetched visit table may take: the
+# chip has 1 MiB of SMEM per core, shared with the compiler's own scalars;
+# larger batches run as a loop over query chunks that each fit
+SMEM_VISIT_BYTES = 512 << 10
 
 
 @functools.partial(jax.jit,
@@ -167,64 +170,100 @@ def ivf_adc(bucket_codes, bucket_ids, visit, luts, coarse, *, k: int,
     Q, T = visit.shape
     spp = steps_per_probe
     assert T % spp == 0, (T, spp)
+    nprobe = T // spp
     per_probe = luts.ndim == 4
     ksub = luts.shape[-1]
-    scales = None
-    if lut_dtype == "int8":
-        luts, scales = quantize_lut_int8(luts)
-    elif jnp.dtype(lut_dtype) != jnp.float32:
-        luts = luts.astype(jnp.dtype(lut_dtype))
-    nprobe = T // spp
-    lut_shape = (Q, nprobe, m * ksub) if per_probe else (Q, m * ksub)
-    luts_flat = luts.reshape(lut_shape)
-
-    # every index_map sees the prefetched visit table as its last arg
-    in_specs = [
-        pl.BlockSpec((1, blk, m), lambda q, t, v: (v[q, t], 0, 0)),
-        pl.BlockSpec((1, blk), lambda q, t, v: (v[q, t], 0)),
-        (pl.BlockSpec((1, 1, m * ksub), lambda q, t, v: (q, t // spp, 0))
-         if per_probe else
-         pl.BlockSpec((1, m * ksub), lambda q, t, v: (q, 0))),
-        pl.BlockSpec((1, 1), lambda q, t, v: (q, t // spp)),
-    ]
-    args = [bucket_codes.astype(jnp.int32), bucket_ids.astype(jnp.int32),
-            luts_flat, coarse.astype(jnp.float32)]
+    luts, scales = _prep_luts(luts, lut_dtype)
+    # per-row operands gain a unit axis so every block's last two dims
+    # equal the array's (the TPU lowering's rule for non-(8, 128) blocks)
+    P = nprobe if per_probe else 1
+    codes = bucket_codes.astype(jnp.int32)
+    ids = bucket_ids.astype(jnp.int32).reshape(B, 1, blk)
+    per_q = [visit.astype(jnp.int32), luts.reshape(Q, P, 1, m * ksub),
+             coarse.astype(jnp.float32).reshape(Q, 1, nprobe)]
     if scales is not None:
-        in_specs.append(
-            pl.BlockSpec((1, 1, m), lambda q, t, v: (q, t // spp, 0))
-            if per_probe else
-            pl.BlockSpec((1, m), lambda q, t, v: (q, 0)))
-        args.append(scales)
+        per_q.append(scales.reshape(Q, P, 1, m))
 
-    kernel = functools.partial(_ivf_adc_kernel, n_steps=T, k=k, ksub=ksub,
-                               int8=scales is not None)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(Q, T),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, k), lambda q, t, v: (q, 0)),
-            pl.BlockSpec((1, k), lambda q, t, v: (q, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, k), jnp.float32),
-            pltpu.VMEM((1, k), jnp.int32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((Q, k), jnp.float32),
-            jax.ShapeDtypeStruct((Q, k), jnp.int32),
-        ],
-        interpret=interpret,
-    )(visit.astype(jnp.int32), *args)
+    def run(visit_c, luts_c, coarse_c, *scales_c):
+        qc = visit_c.shape[0]
+        if per_probe:
+            def row(q, t):
+                return q * P + t // spp
+        else:
+            def row(q, t):
+                return q
+        # every index_map sees the prefetched (flattened) visit table last
+        in_specs = [
+            pl.BlockSpec((1, blk, m), lambda q, t, v: (v[q * T + t], 0, 0)),
+            pl.BlockSpec((1, 1, blk), lambda q, t, v: (v[q * T + t], 0, 0)),
+            pl.BlockSpec((1, 1, m * ksub),
+                         lambda q, t, v: (row(q, t), 0, 0)),
+            pl.BlockSpec((1, 1, nprobe), lambda q, t, v: (q, 0, 0)),
+        ]
+        args = [codes, ids, luts_c.reshape(qc * P, 1, m * ksub), coarse_c]
+        if scales_c:
+            in_specs.append(
+                pl.BlockSpec((1, 1, m), lambda q, t, v: (row(q, t), 0, 0)))
+            args.append(scales_c[0].reshape(qc * P, 1, m))
+        kernel = functools.partial(_ivf_adc_kernel, n_steps=T, spp=spp, k=k,
+                                   ksub=ksub, int8=bool(scales_c))
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(qc, T),
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((1, 1, k), lambda q, t, v: (q, 0, 0)),
+                pl.BlockSpec((1, 1, k), lambda q, t, v: (q, 0, 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((1, k), jnp.float32),
+                pltpu.VMEM((1, k), jnp.int32),
+            ],
+        )
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=[
+                jax.ShapeDtypeStruct((qc, 1, k), jnp.float32),
+                jax.ShapeDtypeStruct((qc, 1, k), jnp.int32),
+            ],
+            interpret=interpret,
+        )(visit_c.reshape(qc * T), *args)
+
+    qc = max(1, min(Q, SMEM_VISIT_BYTES // (4 * T)))
+    if qc == Q:
+        s, i = run(*per_q)
+    else:
+        # query chunks padded to a whole number (pad rows are sliced off)
+        n_c = -(-Q // qc)
+        per_q = [jnp.pad(x, ((0, n_c * qc - Q),) + ((0, 0),) * (x.ndim - 1)
+                         ).reshape((n_c, qc) + x.shape[1:]) for x in per_q]
+        s, i = jax.lax.map(lambda xs: run(*xs), per_q)
+    return s.reshape(-1, k)[:Q], i.reshape(-1, k)[:Q]
 
 
-def _ivf_adc_blocked_kernel(sb_ref, qrow_ref, c_ref, id_ref, panel_ref,
-                            cpan_ref, *refs, n_groups: int, n_q: int, k: int,
-                            ksub: int, int8: bool):
+def _merge_slots(s, ids, qrow_ref, prow_ref, base, coarse_ref, bs_ref,
+                 bi_ref, k: int):
+    """Fold each slot's (1, blk) scores into its query's scoreboard row.
+    Slot i scores pair (qrow[base + i], probe prow[base + i]); its coarse
+    term comes from that pair's row of the (Q + 1, nprobe) coarse table,
+    whose last row is NEG_INF — the trash row sentinel slots land in."""
+    for slot in range(s.shape[0]):  # static unroll: qblk dynamic-row RMWs
+        row = qrow_ref[base + slot]
+        ss = s[slot:slot + 1, :] + _probe_coarse(
+            coarse_ref[pl.ds(row, 1), :], prow_ref[base + slot])
+        ss = jnp.where(ids >= 0, ss, NEG_INF)
+        comb_s = jnp.concatenate([bs_ref[pl.ds(row, 1), :], ss], axis=1)
+        comb_i = jnp.concatenate([bi_ref[pl.ds(row, 1), :], ids], axis=1)
+        ns, ni = _select_topk(comb_s, comb_i, k)
+        bs_ref[pl.ds(row, 1), :] = ns
+        bi_ref[pl.ds(row, 1), :] = ni
+
+
+def _ivf_adc_blocked_kernel(sb_ref, qrow_ref, prow_ref, c_ref, id_ref,
+                            panel_ref, coarse_ref, *refs, n_groups: int,
+                            n_q: int, k: int, ksub: int, qblk: int,
+                            int8: bool):
     if int8:
         scp_ref, s_out, i_out, bs_ref, bi_ref = refs
     else:
@@ -237,45 +276,49 @@ def _ivf_adc_blocked_kernel(sb_ref, qrow_ref, c_ref, id_ref, panel_ref,
         bs_ref[...] = jnp.full_like(bs_ref, NEG_INF)
         bi_ref[...] = jnp.full_like(bi_ref, -1)
 
-    codes = c_ref[...][0]   # (blk, m) int32 — the group's SHARED code block
-    ids = id_ref[...]       # (1, blk) int32 global row ids, -1 = pad slot
-    blk, m = codes.shape
-    sub = jax.lax.broadcasted_iota(jnp.int32, (blk, m, ksub), 2)
-    sel = codes[:, :, None] == sub
-    panel = panel_ref[...][0]  # (qblk, m*ksub) — the group's LUT rows
-    if int8:
-        scale = scp_ref[...][0]  # (qblk, m) f32
-        sel8 = sel.astype(jnp.int8)
-        s = None
-        for j in range(m):
-            pj = jax.lax.dot_general(
-                panel[:, j * ksub:(j + 1) * ksub], sel8[:, j, :],
-                (((1,), (1,)), ((), ())), preferred_element_type=jnp.int32)
-            pj = pj.astype(jnp.float32) * scale[:, j][:, None]
-            s = pj if s is None else s + pj
-    else:
-        sel_f = sel.astype(panel.dtype).reshape(blk, m * ksub)
-        s = jax.lax.dot_general(panel, sel_f, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-    # cpan folds the per-pair coarse term, the caller's probe knockout, and
-    # the sentinel knockout (NEG_INF for padded slots of a partial group)
-    s = s + cpan_ref[...][0][:, None]   # (qblk, blk)
-    s = jnp.where(ids >= 0, s, NEG_INF)
-
-    qblk = s.shape[0]
-    for slot in range(qblk):  # static unroll: qblk dynamic-row RMWs
-        row = qrow_ref[g, slot]  # scoreboard row; n_q = the trash row
-        comb_s = jnp.concatenate([bs_ref[pl.ds(row, 1), :],
-                                  s[slot:slot + 1, :]], axis=1)
-        comb_i = jnp.concatenate([bi_ref[pl.ds(row, 1), :], ids], axis=1)
-        ns, ni = _select_topk(comb_s, comb_i, k)
-        bs_ref[pl.ds(row, 1), :] = ns
-        bi_ref[pl.ds(row, 1), :] = ni
+    ids = id_ref[0]         # (1, blk) int32 global row ids, -1 = pad slot
+    # the group's SHARED code block against its (qblk, m*ksub) LUT rows
+    s = adc_block_scores(panel_ref[0], c_ref[0], ksub,
+                         None if scp_ref is None else scp_ref[0])
+    _merge_slots(s, ids, qrow_ref, prow_ref, g * qblk, coarse_ref, bs_ref,
+                 bi_ref, k)
 
     @pl.when(g == n_groups - 1)
     def _finalize():
         s_out[...] = bs_ref[0:n_q, :]
         i_out[...] = bi_ref[0:n_q, :]
+
+
+def _group_panels(luts, coarse, sched_q, sched_t, spp: int):
+    """Pre-gather the grouped grids' operands: one LUT row per (q, probe)
+    pair — the same per-pair LUT traffic the per-query grid pays, laid out
+    so the contraction is a matmul. Sentinel slots read row 0 and score
+    against the NEG_INF trash row of the padded coarse table.
+    -> (panel (G, qblk, m*ksub), rows (G, qblk) LUT row per pair,
+    qrow (G*qblk,) scoreboard row per pair (Q for sentinels),
+    prow (G*qblk,) probe per pair, coarse (Q + 1, nprobe) f32 with the
+    NEG_INF trash row appended)."""
+    G, qblk = sched_q.shape
+    Q, nprobe = coarse.shape
+    per_probe = luts.ndim == 4
+    m, ksub = luts.shape[-2:]
+    qs = jnp.clip(sched_q, 0)
+    p_of = sched_t // spp
+    n_rows = Q * nprobe if per_probe else Q
+    rows = qs * nprobe + p_of if per_probe else qs
+    panel = jnp.take(luts.reshape(n_rows, m * ksub), rows.reshape(-1),
+                     axis=0).reshape(G, qblk, m * ksub)
+    qrow = jnp.where(sched_q >= 0, sched_q, Q).astype(jnp.int32)
+    coarse = jnp.concatenate([coarse.astype(jnp.float32),
+                              jnp.full((1, nprobe), NEG_INF, jnp.float32)])
+    return (panel, rows, qrow.reshape(G * qblk),
+            p_of.astype(jnp.int32).reshape(G * qblk), coarse)
+
+
+def _group_scales(scales, rows, n_rows: int, m: int):
+    G, qblk = rows.shape
+    return jnp.take(scales.reshape(n_rows, m), rows.reshape(-1),
+                    axis=0).reshape(G, qblk, m)
 
 
 @functools.partial(jax.jit,
@@ -294,65 +337,43 @@ def ivf_adc_blocked(bucket_codes, bucket_ids, sched_block, sched_q, sched_t,
     uniform across shared and per-probe LUT geometry), and merges each
     slot's scores into a per-query (1, k) scoreboard row.
 
-    Scores are bit-identical to the per-query grid: the f32/bf16 panel
-    contraction reduces over the same m*ksub order, and the int8 path
-    accumulates the same per-subspace f32 partials in the same j order.
+    Scores are bit-identical to the per-query grid: both fold the same
+    exact per-subspace partials in the same j order (``adc_block_scores``).
     -> (scores (Q, k) f32, ids (Q, k) int32), NEG_INF/-1 sentinels as in
     ``ivf_adc`` (the ops.py dispatcher normalizes).
     """
     B, blk, m = bucket_codes.shape
     G, qblk = sched_q.shape
     Q, nprobe = coarse.shape
-    spp = steps_per_probe
-    per_probe = luts.ndim == 4
     ksub = luts.shape[-1]
-    scales = None
-    if lut_dtype == "int8":
-        luts, scales = quantize_lut_int8(luts)
-    elif jnp.dtype(lut_dtype) != jnp.float32:
-        luts = luts.astype(jnp.dtype(lut_dtype))
-
-    # pre-gather the (G, qblk, m*ksub) LUT panels: one row per (q, probe)
-    # pair — the same per-pair LUT traffic the per-query grid pays, laid
-    # out so the contraction is a matmul. Sentinel slots read row 0 and are
-    # knocked out via cpan.
-    qs = jnp.clip(sched_q, 0)
-    p_of = sched_t // spp
-    n_rows = Q * nprobe if per_probe else Q
-    row = qs * nprobe + p_of if per_probe else qs
-    luts_rows = luts.reshape(n_rows, m * ksub)
-    panel = jnp.take(luts_rows, row.reshape(-1), axis=0
-                     ).reshape(G, qblk, m * ksub)
-    cpan = jnp.take(coarse.astype(jnp.float32).reshape(-1),
-                    (qs * nprobe + p_of).reshape(-1)).reshape(G, qblk)
-    cpan = jnp.where(sched_q >= 0, cpan, NEG_INF)
-    qrow = jnp.where(sched_q >= 0, sched_q, Q).astype(jnp.int32)
+    luts, scales = _prep_luts(luts, lut_dtype)
+    panel, rows, qrow, prow, coarse_t = _group_panels(
+        luts, coarse, sched_q, sched_t, steps_per_probe)
 
     in_specs = [
-        pl.BlockSpec((1, blk, m), lambda g, sb, qr: (sb[g], 0, 0)),
-        pl.BlockSpec((1, blk), lambda g, sb, qr: (sb[g], 0)),
-        pl.BlockSpec((1, qblk, m * ksub), lambda g, sb, qr: (g, 0, 0)),
-        pl.BlockSpec((1, qblk), lambda g, sb, qr: (g, 0)),
+        pl.BlockSpec((1, blk, m), lambda g, sb, qr, pr: (sb[g], 0, 0)),
+        pl.BlockSpec((1, 1, blk), lambda g, sb, qr, pr: (sb[g], 0, 0)),
+        pl.BlockSpec((1, qblk, m * ksub), lambda g, sb, qr, pr: (g, 0, 0)),
+        pl.BlockSpec((Q + 1, nprobe), lambda g, sb, qr, pr: (0, 0)),
     ]
-    args = [bucket_codes.astype(jnp.int32), bucket_ids.astype(jnp.int32),
-            panel, cpan]
+    args = [bucket_codes.astype(jnp.int32),
+            bucket_ids.astype(jnp.int32).reshape(B, 1, blk), panel, coarse_t]
     if scales is not None:
-        scale_rows = scales.reshape(n_rows, m)
-        scpan = jnp.take(scale_rows, row.reshape(-1), axis=0
-                         ).reshape(G, qblk, m)
+        n_rows = Q * nprobe if luts.ndim == 4 else Q
         in_specs.append(
-            pl.BlockSpec((1, qblk, m), lambda g, sb, qr: (g, 0, 0)))
-        args.append(scpan)
+            pl.BlockSpec((1, qblk, m), lambda g, sb, qr, pr: (g, 0, 0)))
+        args.append(_group_scales(scales, rows, n_rows, m))
 
     kernel = functools.partial(_ivf_adc_blocked_kernel, n_groups=G, n_q=Q,
-                               k=k, ksub=ksub, int8=scales is not None)
+                               k=k, ksub=ksub, qblk=qblk,
+                               int8=scales is not None)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(G,),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((Q, k), lambda g, sb, qr: (0, 0)),
-            pl.BlockSpec((Q, k), lambda g, sb, qr: (0, 0)),
+            pl.BlockSpec((Q, k), lambda g, sb, qr, pr: (0, 0)),
+            pl.BlockSpec((Q, k), lambda g, sb, qr, pr: (0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((Q + 1, k), jnp.float32),  # row Q = sentinel trash
@@ -367,19 +388,18 @@ def ivf_adc_blocked(bucket_codes, bucket_ids, sched_block, sched_q, sched_t,
             jax.ShapeDtypeStruct((Q, k), jnp.int32),
         ],
         interpret=interpret,
-    )(sched_block.astype(jnp.int32), qrow, *args)
+    )(sched_block.astype(jnp.int32), qrow, prow, *args)
 
 
-def _ivf_adc_run_resident_kernel(rb_ref, rs_ref, rl_ref, qrow_ref, c_ref,
-                                 id_ref, panel_hbm, cpan_ref, *refs,
+def _ivf_adc_run_resident_kernel(rb_ref, rs_ref, rl_ref, qrow_ref, prow_ref,
+                                 c_ref, id_ref, panel_hbm, coarse_ref, *refs,
                                  n_runs: int, n_q: int, k: int, ksub: int,
                                  qblk: int, int8: bool):
     if int8:
-        (scp_hbm, s_out, i_out,
-         bs_ref, bi_ref, pbuf, psem, sbuf, ssem) = refs
+        scp_hbm, s_out, i_out, bs_ref, bi_ref, pbuf, sem, sbuf = refs
     else:
-        scp_hbm = sbuf = ssem = None
-        s_out, i_out, bs_ref, bi_ref, pbuf, psem = refs
+        scp_hbm = sbuf = None
+        s_out, i_out, bs_ref, bi_ref, pbuf, sem = refs
     r = pl.program_id(0)
 
     @pl.when(r == 0)
@@ -387,34 +407,24 @@ def _ivf_adc_run_resident_kernel(rb_ref, rs_ref, rl_ref, qrow_ref, c_ref,
         bs_ref[...] = jnp.full_like(bs_ref, NEG_INF)
         bi_ref[...] = jnp.full_like(bi_ref, -1)
 
-    codes = c_ref[...][0]   # (blk, m) int32 — THE run's code block
-    ids = id_ref[...]       # (1, blk) int32 global row ids, -1 = pad slot
-    blk, m = codes.shape
-    # the amortization: the block's one-hot selector expands ONCE per run;
-    # every group in the run contracts against it
-    sub = jax.lax.broadcasted_iota(jnp.int32, (blk, m, ksub), 2)
-    sel = codes[:, :, None] == sub
-    if int8:
-        sel_c = sel.astype(jnp.int8)
-    else:
-        sel_c = sel.astype(pbuf.dtype).reshape(blk, m * ksub)
-
+    codes = c_ref[0]        # (blk, m) int32 — THE run's code block
+    ids = id_ref[0]         # (1, blk) int32 global row ids, -1 = pad slot
     g0 = rs_ref[r]
     L = rl_ref[r]           # groups in this run (0 for pad runs)
 
-    def dma_panel(slot, g):
-        return pltpu.make_async_copy(panel_hbm.at[pl.ds(g, 1)],
-                                     pbuf.at[slot], psem.at[slot])
-
-    def dma_scale(slot, g):
-        return pltpu.make_async_copy(scp_hbm.at[pl.ds(g, 1)],
-                                     sbuf.at[slot], ssem.at[slot])
+    def copies(slot, g):
+        """The group's LUT panel (and int8 scales) DMAs."""
+        out = [pltpu.make_async_copy(panel_hbm.at[pl.ds(g, 1)],
+                                     pbuf.at[slot], sem.at[0, slot])]
+        if int8:
+            out.append(pltpu.make_async_copy(scp_hbm.at[pl.ds(g, 1)],
+                                             sbuf.at[slot], sem.at[1, slot]))
+        return out
 
     @pl.when(L > 0)
     def _warm():                      # first panel in flight before the loop
-        dma_panel(0, g0).start()
-        if int8:
-            dma_scale(0, g0).start()
+        for c in copies(0, g0):
+            c.start()
 
     def body(j, carry):
         slot = jax.lax.rem(j, 2)
@@ -422,37 +432,15 @@ def _ivf_adc_run_resident_kernel(rb_ref, rs_ref, rl_ref, qrow_ref, c_ref,
 
         @pl.when(j + 1 < L)
         def _prefetch():              # next panel races the contraction
-            dma_panel(1 - slot, g + 1).start()
-            if int8:
-                dma_scale(1 - slot, g + 1).start()
+            for c in copies(1 - slot, g + 1):
+                c.start()
 
-        dma_panel(slot, g).wait()
-        panel = pbuf[slot, 0]         # (qblk, m*ksub)
-        if int8:
-            dma_scale(slot, g).wait()
-            scale = sbuf[slot, 0]     # (qblk, m) f32
-            s = None
-            for j_sub in range(m):
-                pj = jax.lax.dot_general(
-                    panel[:, j_sub * ksub:(j_sub + 1) * ksub],
-                    sel_c[:, j_sub, :], (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.int32)
-                pj = pj.astype(jnp.float32) * scale[:, j_sub][:, None]
-                s = pj if s is None else s + pj
-        else:
-            s = jax.lax.dot_general(panel, sel_c, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-        s = s + cpan_ref[pl.ds(g, 1), :][0][:, None]   # (qblk, blk)
-        s = jnp.where(ids >= 0, s, NEG_INF)
-
-        for slot_i in range(qblk):    # static unroll: qblk dynamic-row RMWs
-            row = qrow_ref[g, slot_i]
-            comb_s = jnp.concatenate([bs_ref[pl.ds(row, 1), :],
-                                      s[slot_i:slot_i + 1, :]], axis=1)
-            comb_i = jnp.concatenate([bi_ref[pl.ds(row, 1), :], ids], axis=1)
-            ns, ni = _select_topk(comb_s, comb_i, k)
-            bs_ref[pl.ds(row, 1), :] = ns
-            bi_ref[pl.ds(row, 1), :] = ni
+        for c in copies(slot, g):
+            c.wait()
+        s = adc_block_scores(pbuf[slot, 0], codes, ksub,
+                             None if sbuf is None else sbuf[slot, 0])
+        _merge_slots(s, ids, qrow_ref, prow_ref, g * qblk, coarse_ref,
+                     bs_ref, bi_ref, k)
         return carry
 
     jax.lax.fori_loop(0, L, body, 0)
@@ -481,12 +469,11 @@ def ivf_adc_run_resident(bucket_codes, bucket_ids, run_block, run_start,
 
     Program r fetches block run_block[r] ONCE for the whole batch (the
     grid pipeline double-buffers the next run's block against the current
-    run's work), expands its one-hot selector once, then loops the run's
-    groups with an inner fori_loop, manually double-buffering each group's
-    (qblk, m*ksub) LUT panel DMA against the previous group's contraction
-    + scoreboard merge. Scores are bit-identical to the per-query and
-    blocked grids (same contraction orders; the int8 path accumulates the
-    same per-subspace f32 partials in the same j order).
+    run's work), then loops the run's groups with an inner fori_loop,
+    manually double-buffering each group's (qblk, m*ksub) LUT panel DMA
+    against the previous group's contraction +
+    scoreboard merge. Scores are bit-identical to the per-query and
+    blocked grids (``adc_block_scores``).
     -> (scores (Q, k) f32, ids (Q, k) int32), NEG_INF/-1 sentinels as in
     ``ivf_adc`` (the ops.py dispatcher normalizes).
     """
@@ -494,62 +481,49 @@ def ivf_adc_run_resident(bucket_codes, bucket_ids, run_block, run_start,
     G, qblk = sched_q.shape
     R = run_block.shape[0]
     Q, nprobe = coarse.shape
-    spp = steps_per_probe
-    per_probe = luts.ndim == 4
     ksub = luts.shape[-1]
-    scales = None
-    if lut_dtype == "int8":
-        luts, scales = quantize_lut_int8(luts)
-    elif jnp.dtype(lut_dtype) != jnp.float32:
-        luts = luts.astype(jnp.dtype(lut_dtype))
-
-    # same pre-gathered panel geometry as the blocked grid; here it stays
-    # in HBM (memory_space=ANY) and the kernel streams it per group
-    qs = jnp.clip(sched_q, 0)
-    p_of = sched_t // spp
-    n_rows = Q * nprobe if per_probe else Q
-    row = qs * nprobe + p_of if per_probe else qs
-    luts_rows = luts.reshape(n_rows, m * ksub)
-    panel = jnp.take(luts_rows, row.reshape(-1), axis=0
-                     ).reshape(G, qblk, m * ksub)
-    cpan = jnp.take(coarse.astype(jnp.float32).reshape(-1),
-                    (qs * nprobe + p_of).reshape(-1)).reshape(G, qblk)
-    cpan = jnp.where(sched_q >= 0, cpan, NEG_INF)
-    qrow = jnp.where(sched_q >= 0, sched_q, Q).astype(jnp.int32)
+    luts, scales = _prep_luts(luts, lut_dtype)
+    # same pre-gathered geometry as the blocked grid; the panels stay in
+    # HBM and the kernel streams them per group
+    panel, rows, qrow, prow, coarse_t = _group_panels(
+        luts, coarse, sched_q, sched_t, steps_per_probe)
 
     in_specs = [
-        pl.BlockSpec((1, blk, m), lambda r, rb, rs, rl, qr: (rb[r], 0, 0)),
-        pl.BlockSpec((1, blk), lambda r, rb, rs, rl, qr: (rb[r], 0)),
-        pl.BlockSpec(memory_space=pltpu.ANY),         # panel: streamed
-        pl.BlockSpec((G, qblk), lambda r, rb, rs, rl, qr: (0, 0)),
+        pl.BlockSpec((1, blk, m),
+                     lambda r, rb, rs, rl, qr, pr: (rb[r], 0, 0)),
+        pl.BlockSpec((1, 1, blk),
+                     lambda r, rb, rs, rl, qr, pr: (rb[r], 0, 0)),
+        pl.BlockSpec(memory_space=pl.ANY),         # panel: streamed
+        pl.BlockSpec((Q + 1, nprobe), lambda r, rb, rs, rl, qr, pr: (0, 0)),
     ]
-    args = [bucket_codes.astype(jnp.int32), bucket_ids.astype(jnp.int32),
-            panel, cpan]
+    args = [bucket_codes.astype(jnp.int32),
+            bucket_ids.astype(jnp.int32).reshape(B, 1, blk), panel, coarse_t]
     scratch = [
         pltpu.VMEM((Q + 1, k), jnp.float32),  # row Q = sentinel trash
         pltpu.VMEM((Q + 1, k), jnp.int32),
         pltpu.VMEM((2, 1, qblk, m * ksub), panel.dtype),
-        pltpu.SemaphoreType.DMA((2,)),
+        pltpu.SemaphoreType.DMA((2 if scales is not None else 1, 2)),
     ]
     if scales is not None:
-        scale_rows = scales.reshape(n_rows, m)
-        scpan = jnp.take(scale_rows, row.reshape(-1), axis=0
-                         ).reshape(G, qblk, m)
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
-        args.append(scpan)
-        scratch += [pltpu.VMEM((2, 1, qblk, m), jnp.float32),
-                    pltpu.SemaphoreType.DMA((2,))]
+        n_rows = Q * nprobe if luts.ndim == 4 else Q
+        # lanes pad to the 128 tiling so each group's slice is a whole-tile
+        # DMA; the kernel reads only the first m columns
+        mp = -(-m // 128) * 128
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        args.append(jnp.pad(_group_scales(scales, rows, n_rows, m),
+                            ((0, 0), (0, 0), (0, mp - m))))
+        scratch.append(pltpu.VMEM((2, 1, qblk, mp), jnp.float32))
 
     kernel = functools.partial(_ivf_adc_run_resident_kernel, n_runs=R,
                                n_q=Q, k=k, ksub=ksub, qblk=qblk,
                                int8=scales is not None)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
+        num_scalar_prefetch=5,
         grid=(R,),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((Q, k), lambda r, rb, rs, rl, qr: (0, 0)),
-            pl.BlockSpec((Q, k), lambda r, rb, rs, rl, qr: (0, 0)),
+            pl.BlockSpec((Q, k), lambda r, rb, rs, rl, qr, pr: (0, 0)),
+            pl.BlockSpec((Q, k), lambda r, rb, rs, rl, qr, pr: (0, 0)),
         ],
         scratch_shapes=scratch,
     )
@@ -562,4 +536,4 @@ def ivf_adc_run_resident(bucket_codes, bucket_ids, run_block, run_start,
         ],
         interpret=interpret,
     )(run_block.astype(jnp.int32), run_start.astype(jnp.int32),
-      run_len.astype(jnp.int32), qrow, *args)
+      run_len.astype(jnp.int32), qrow, prow, *args)

@@ -59,6 +59,25 @@ BLOCKED_MIN_SHARING = 2.0
 BLOCKED_MIN_QUERIES = 32
 BLOCKED_MAX_BOARD_SLOTS = 1 << 25
 DEFAULT_QBLK = 8  # f32 sublane tile — groups land MXU-aligned
+# The kernel backend's grouped grids pre-gather one m*ksub LUT row per
+# scheduled (query, block) slot into an HBM panel — 96 KiB a slot at m=96,
+# ksub=256 in f32, so a low-sharing batch's panel runs to gigabytes — and
+# prefetch the schedule into the core's 1 MiB of scalar memory. Auto
+# dispatch keeps a batch off those grids unless both bounds hold.
+GROUPED_MAX_PANEL_BYTES = 256 << 20
+GROUPED_MAX_SMEM_BYTES = 512 << 10
+
+
+def _kernel_grouped_fits(pairs: int, blocks: int, qblk: int,
+                         row_bytes: int) -> bool:
+    """Upper bounds, before the schedule is built, on the grouped kernels'
+    panel bytes and scalar-prefetch bytes (``build_block_schedule`` pads
+    groups and runs by at most a quarter)."""
+    slots = (pairs + blocks * qblk) * 5 // 4   # >= G * qblk after the pad
+    runs = (blocks + 1) * 5 // 4 + 8
+    smem = 4 * (2 * slots + slots // qblk + 3 * runs)
+    return (slots * row_bytes <= GROUPED_MAX_PANEL_BYTES
+            and smem <= GROUPED_MAX_SMEM_BYTES)
 
 
 def _auto_interpret(interpret):
@@ -672,11 +691,21 @@ def ivf_adc_topk(bucket_codes, bucket_ids, visit, luts, *, k: int,
         # full schedule is only built when a grouped grid will consume it
         sstats.update(visit_sharing(visit_np, pad_block=pad_block))
         board_ok = (Q + 1) * T * blk <= BLOCKED_MAX_BOARD_SLOTS
+        row_bytes = m * luts.shape[-1] * (
+            4 if lut_dtype == "float32" else 2 if lut_dtype == "bfloat16"
+            else 5)  # int8 entries + their share of the f32 scales
+
+        def fits(qb):
+            # the scatter board bounds the twins, panel + SMEM the kernels
+            if backend == "jnp":
+                return board_ok
+            return _kernel_grouped_fits(sstats["pairs"], sstats["blocks"],
+                                        qb, row_bytes)
         if mode != "auto":
             grid = mode
         elif autotune is False:
             # PR-8 constant heuristic, kept as the untuned escape hatch
-            if (Q >= BLOCKED_MIN_QUERIES and board_ok
+            if (Q >= BLOCKED_MIN_QUERIES and fits(eff_qblk)
                     and sstats["sharing"] >= BLOCKED_MIN_SHARING):
                 grid = "blocked"
         else:
@@ -685,17 +714,20 @@ def ivf_adc_topk(bucket_codes, bucket_ids, visit, luts, *, k: int,
             entry = tuner.lookup(tkey)
             if entry is not None:
                 sstats["crossover"] = entry["crossover"]
-                if (sstats["pairs"] > 0 and board_ok
+                e_qblk = entry["qblk"] if qblk is None else qblk
+                if (sstats["pairs"] > 0 and fits(e_qblk)
                         and sstats["sharing"] >= entry["crossover"]):
                     grid = entry["grouped_mode"]
-                    eff_qblk = entry["qblk"] if qblk is None else qblk
-            elif sstats["pairs"] > 0 and board_ok:
+                    eff_qblk = e_qblk
+            elif sstats["pairs"] > 0:
                 probe_cfg = tuner.next_probe(tkey)
-                if probe_cfg is not None:
+                p_qblk = (probe_cfg[1] or eff_qblk) if probe_cfg else 0
+                if probe_cfg is not None and fits(p_qblk):
                     grid = probe_cfg[0]
-                    if probe_cfg[1]:
-                        eff_qblk = probe_cfg[1]
+                    eff_qblk = p_qblk
                     sstats["probe"] = True
+                else:
+                    probe_cfg = None  # probes wait for a batch that fits
     built = None
     if grid != "per_query":
         built = _build_schedule_cached(visit_np, eff_qblk, pad_block,

@@ -11,7 +11,8 @@ the concatenated (running || tile) scores — only max/argmax/iota/where, all
 Mosaic-friendly vector ops (lax.top_k does not lower to TPU). k is static
 and small (<= 64), so the rounds unroll.
 
-Grid: (N / blk_n,), sequential on TPU. l2 mode fuses the -|c|^2 epilogue from
+Grid: (N / blk_n,), sequential on TPU. The (N,) score bias rides as a
+(1, N) row so its (1, blk_n) blocks sit on the lane tiling. l2 mode fuses the -|c|^2 epilogue from
 a precomputed corpus_sq tile; q_sq is a rank-0 shift that cannot change the
 ranking and is added by the ops.py wrapper for score parity with the oracle.
 """
@@ -59,7 +60,7 @@ def _topk_kernel(c_ref, q_ref, bias_ref, s_out, i_out, bs_ref, bi_ref, *,
     if l2:
         s = 2.0 * s
     # bias folds in the metric epilogue (-|c|^2 for l2) AND pad-row knockout
-    s = s + bias_ref[...][None, :]
+    s = s + bias_ref[...]
     Q = s.shape[0]
     ids = ni * blk_n + jax.lax.broadcasted_iota(jnp.int32, (Q, blk_n), 1)
 
@@ -80,12 +81,14 @@ def topk_distance(corpus, q, *, k: int, l2: bool = False, bias=None,
 
     Scores are dot products (l2=False) or -(|q|^2 - 2 q.c + |c|^2) (l2=True).
     ``bias`` (N,) is added to every query's scores — the l2 -|c|^2 epilogue
-    and/or -inf pad-row knockout (built by ops.py). N must divide by blk_n.
+    and/or -inf pad-row knockout (built by ops.py). N must divide by blk_n,
+    and blk_n must be N or a multiple of 128 (the bias block's lane width).
     """
     N, d = corpus.shape
     Q = q.shape[0]
     blk_n = min(blk_n, N)
     assert N % blk_n == 0, (N, blk_n)
+    assert blk_n == N or blk_n % 128 == 0, blk_n
     n_blocks = N // blk_n
     if bias is None:
         bias = (-jnp.sum(jnp.square(corpus.astype(jnp.float32)), axis=-1)
@@ -99,7 +102,7 @@ def topk_distance(corpus, q, *, k: int, l2: bool = False, bias=None,
         in_specs=[
             pl.BlockSpec((blk_n, d), lambda n: (n, 0)),
             pl.BlockSpec((Q, d), lambda n: (0, 0)),
-            pl.BlockSpec((blk_n,), lambda n: (n,)),
+            pl.BlockSpec((1, blk_n), lambda n: (0, n)),
         ],
         out_specs=[
             pl.BlockSpec((Q, k), lambda n: (0, 0)),
@@ -114,7 +117,7 @@ def topk_distance(corpus, q, *, k: int, l2: bool = False, bias=None,
             pltpu.VMEM((Q, k), jnp.int32),
         ],
         interpret=interpret,
-    )(corpus, q, bias)
+    )(corpus, q, bias.astype(jnp.float32).reshape(1, N))
     if l2:
         s = s - jnp.sum(jnp.square(q.astype(jnp.float32)), axis=-1, keepdims=True)
     return s, i

@@ -25,7 +25,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import GNNConfig
 
 
@@ -99,18 +98,16 @@ def make_partitioned_loss(cfg: GNNConfig, mesh: Mesh, dp_axes, n_nodes: int):
         acc_sum = jnp.sum(jnp.where(label_mask, jnp.argmax(logits, -1) == labels,
                                     False).astype(jnp.float32))
         # per-shard partial sums, reduced OUTSIDE the shard_map: a psum here
-        # sits on the loss's gradient path, and jax 0.4.x cannot transpose
-        # psum under check_rep=False (rank-0 cotangents pick up the psum axis
-        # names and fail the out-spec check). The (1, 3) row concatenates to
-        # (n_shards, 3) under P(dp, None); summing that is the same collective
-        # but in jit-land where AD is routine.
+        # would sit on the loss's gradient path under check_vma=False. The
+        # (1, 3) row concatenates to (n_shards, 3) under P(dp, None); summing
+        # that is the same collective but in jit-land where AD is routine.
         return jnp.stack([loss_sum, n, acc_sum])[None, :]
 
     def loss_fn(params, batch):
-        parts = shard_map(
+        parts = jax.shard_map(
             local_loss, mesh=mesh,
             in_specs=(P(), P(dp, None), P(None, dp), P(dp), P(dp), P(dp)),
-            out_specs=P(dp, None), check_replication=False,
+            out_specs=P(dp, None), check_vma=False,
         )(params, batch["feats"], batch["edges"], batch["edge_valid"],
           batch["labels"], batch["label_mask"])
         loss_sum, n, acc_sum = jnp.sum(parts, axis=0)

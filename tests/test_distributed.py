@@ -166,7 +166,10 @@ def test_sharded_lm_train_step_runs_and_matches():
         from repro.models import transformer
         from repro.train import adamw_init
 
-        mesh = jax.make_mesh((4, 2), ('data', 'model'))
+        # the train step places activations with sharding constraints,
+        # which only Auto mesh axes accept (make_mesh defaults to Explicit)
+        mesh = jax.make_mesh((4, 2), ('data', 'model'),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         cfg = get_arch('stablelm-1.6b').smoke
         inputs = {'tokens': jax.ShapeDtypeStruct((8, 32), jnp.int32),
                   'labels': jax.ShapeDtypeStruct((8, 32), jnp.int32)}
@@ -200,7 +203,6 @@ def test_compressed_allreduce_8way():
     run_spmd("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
         from repro.train import make_compressed_allreduce
         from repro.train.compress import init_error_feedback
         mesh = jax.make_mesh((8,), ('dp',))
@@ -210,9 +212,9 @@ def test_compressed_allreduce_8way():
         def f(g, e):
             out, err = allreduce({'w': g}, {'w': e})
             return out['w'], err['w']
-        out, err = shard_map(f, mesh=mesh, in_specs=(P('dp'), P('dp')),
+        out, err = jax.shard_map(f, mesh=mesh, in_specs=(P('dp'), P('dp')),
                              out_specs=(P('dp'), P('dp')),
-                             check_replication=False)(g, e)
+                             check_vma=False)(g, e)
         # each shard's output approximates the mean over shards
         mean = np.mean(np.asarray(g), axis=0)
         got = np.asarray(out)[0]

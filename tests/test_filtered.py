@@ -125,7 +125,7 @@ try:
     @given(seed=st.integers(0, 2 ** 16))
     @settings(max_examples=25, deadline=None)
     def test_predicate_fuzz_hypothesis(seed):
-        test_predicate_fuzz_vs_oracle.__wrapped__(seed)
+        test_predicate_fuzz_vs_oracle(seed)
 except ImportError:  # the seeded fuzzer above always runs
     pass
 

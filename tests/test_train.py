@@ -110,12 +110,11 @@ def test_compressed_allreduce_error_feedback():
     err = init_error_feedback(grads)
 
     def one(g, e):
-        from repro.compat import shard_map
-        return shard_map(lambda gg, ee: allreduce(gg, ee),
+        return jax.shard_map(lambda gg, ee: allreduce(gg, ee),
                          mesh=jax.make_mesh((1,), ("i",)),
                          in_specs=(jax.sharding.PartitionSpec(),) * 2,
                          out_specs=(jax.sharding.PartitionSpec(),) * 2,
-                         check_replication=False)(g, e)
+                         check_vma=False)(g, e)
 
     acc = jnp.zeros_like(grads["w"])
     for _ in range(20):
