@@ -47,6 +47,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import checkpoint as ckpt
+from repro import obs
 from repro.core import distances as D
 from repro.core import distributed as dist
 from repro.core.flat import FlatIndex
@@ -480,9 +481,18 @@ class VectorDB(_PlanLedger, _WriteFront):
         An empty index — never inserted into, or fully deleted — returns
         (Q, 0)-shaped results rather than erroring: emptiness is a normal
         state for a database, unlike querying before ``load``.
+
+        The call is one ``db.query`` span (``repro.obs``) with the rows
+        dispatched (``bucket``) and whether the plan was new (``plan_miss``).
         """
         if not self._loaded:
             raise RuntimeError("query before load")
+        with obs.span("db.query") as sp:
+            return self._query(sp, q, k, bucketize, where, hybrid,
+                               hybrid_texts, hybrid_tokens)
+
+    def _query(self, sp, q, k, bucketize, where, hybrid, hybrid_texts,
+               hybrid_tokens):
         q = jnp.atleast_2d(jnp.asarray(q))
         kk = min(k, self.n)
         if kk <= 0:
@@ -493,7 +503,9 @@ class VectorDB(_PlanLedger, _WriteFront):
             allowed, extra = self._filter_bitmap(where)
         try:
             if bucketize:
+                misses = self.plan_stats["misses"]
                 q, Q = self._plan_batch(q, kk)
+                sp.attrs["plan_miss"] = self.plan_stats["misses"] > misses
                 if hasattr(self.index, "sched_cache"):
                     # hand the engine the ledger's schedule cache + this
                     # batch's plan context; the engine appends nprobe to
@@ -503,6 +515,7 @@ class VectorDB(_PlanLedger, _WriteFront):
                                              self.plan_generation)
             else:
                 Q = q.shape[0]
+            sp.attrs["bucket"] = q.shape[0]
             scores, ids = self.index.query(q, k=kk, **extra)
             scores, ids = scores[:Q], ids[:Q]
         finally:

@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import distances as D
 from repro.core.ivf import (BlockListLayout, assign_clusters,
                             assign_from_buckets, build_block_lists,
@@ -516,10 +517,11 @@ def ivf_pq_search(codebooks, codes, centroids, buckets, corpus, q, *,
     pad_block = bucket_ids.shape[0] - 1
     adaptive = adaptive_nprobe is not None
     threshold = jnp.float32(adaptive_nprobe if adaptive else 0.0)
-    visit, luts, coarse, eff = _ivf_probe_stage(
-        codebooks, centroids, q, block_table, threshold, metric=metric,
-        nprobe=nprobe, steps_per_probe=spp, pad_block=pad_block,
-        adaptive=adaptive)
+    with obs.span("ivf.probe"):
+        visit, luts, coarse, eff = _ivf_probe_stage(
+            codebooks, centroids, q, block_table, threshold, metric=metric,
+            nprobe=nprobe, steps_per_probe=spp, pad_block=pad_block,
+            adaptive=adaptive)
     R = min(max(refine, k), nprobe * spp * blk)
     s, ids = kops.ivf_adc_topk(bucket_codes, bucket_ids, visit, luts, k=R,
                                coarse=coarse, steps_per_probe=spp,
@@ -534,7 +536,9 @@ def ivf_pq_search(codebooks, codes, centroids, buckets, corpus, q, *,
         adc_stats["eff_nprobe"] = (float(jnp.mean(eff)) if adaptive
                                    else float(nprobe))
     if refine:
-        return _exact_rerank(corpus, corpus_sq, ids, q, metric=metric, k=k)
+        with obs.span("ivf.rerank"):
+            return _exact_rerank(corpus, corpus_sq, ids, q, metric=metric,
+                                 k=k)
     return _pad_to_k(s[:, :k], ids[:, :k], k)
 
 
@@ -856,11 +860,13 @@ class IVFPQIndex(MutationMixin):
         self.qblk = qblk  # grouped-grid query-group width; None = autotuned
         # dispatch telemetry: batches served per grid (probe batches counted
         # both under their grid and under 'probes'), running sums for the
-        # mean sharing factor / effective nprobe (serve.engine surfaces them)
+        # mean sharing factor / effective nprobe, and the grid steps of
+        # the batches whose visit table came to the host with those that
+        # visit a real block (serve.engine surfaces them)
         self.adc_stats = {"blocked": 0, "per_query": 0, "run_resident": 0,
                           "probes": 0, "crossover": None,
                           "sharing_sum": 0.0, "eff_nprobe_sum": 0.0,
-                          "batches": 0}
+                          "batches": 0, "steps": 0, "real_steps": 0}
         # installed by the owning VectorDB front: the plan ledger's
         # ScheduleCache + its (bucket, generation) context for this batch
         self.sched_cache = None
@@ -1052,6 +1058,8 @@ class IVFPQIndex(MutationMixin):
                 st["crossover"] = batch_stats["crossover"]
             st["sharing_sum"] += batch_stats["sharing"]
             st["eff_nprobe_sum"] += batch_stats["eff_nprobe"]
+            st["steps"] += batch_stats["steps"]
+            st["real_steps"] += batch_stats["pairs"]
             st["batches"] += 1
         return out
 

@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.kernels import flash_attention as _fa
 from repro.kernels import hamming as _hm
 from repro.kernels import ivf_adc as _ivf
@@ -649,7 +650,13 @@ def ivf_adc_topk(bucket_codes, bucket_ids, visit, luts, *, k: int,
     nprobe)) so steady-state serving stops re-sorting identical visit
     tables. If ``stats`` is a dict, the dispatch decision is written into
     it ('mode', 'sharing', 'pairs', 'blocks', 'groups', 'qblk', 'probe',
-    'crossover').
+    'crossover', and 'steps' = Q * T where the visit table came to the
+    host, else 0; 'pairs' of them visit a real block).
+
+    Host spans (``repro.obs``): ``ivf.visit_sync`` (the visit table's
+    round trip), ``ivf.sharing`` (the sharing probe and grid decision),
+    ``ivf.schedule`` (grouped grids) and ``ivf.adc`` (the grid launch,
+    the autotuner's timed calls included).
 
     ``allowed`` (optional (n,) bool bitmap over the id space — the
     predicate engine's output) rewrites ``bucket_ids`` through
@@ -680,58 +687,65 @@ def ivf_adc_topk(bucket_codes, bucket_ids, visit, luts, *, k: int,
     blk = bucket_codes.shape[1]
     m = bucket_codes.shape[2]
     sstats = {"mode": "per_query", "sharing": 0.0, "pairs": 0, "blocks": 0,
-              "groups": 0, "qblk": 0, "probe": False, "crossover": None}
+              "groups": 0, "qblk": 0, "probe": False, "crossover": None,
+              "steps": 0}
     grid = "per_query"
     eff_qblk = DEFAULT_QBLK if qblk is None else qblk
     probe_cfg = tuner = tkey = visit_np = None
     if not traced and mode != "per_query":
         from repro.core.ivf import visit_sharing  # lazy: layering
-        visit_np = np.asarray(visit)
-        # cheap dispatch input: one np.unique, no sort-and-segment — the
-        # full schedule is only built when a grouped grid will consume it
-        sstats.update(visit_sharing(visit_np, pad_block=pad_block))
-        board_ok = (Q + 1) * T * blk <= BLOCKED_MAX_BOARD_SLOTS
-        row_bytes = m * luts.shape[-1] * (
-            4 if lut_dtype == "float32" else 2 if lut_dtype == "bfloat16"
-            else 5)  # int8 entries + their share of the f32 scales
+        with obs.span("ivf.visit_sync"):
+            visit_np = np.asarray(visit)
+        with obs.span("ivf.sharing") as sharing_span:
+            # cheap dispatch input: one np.unique, no sort-and-segment — the
+            # full schedule is only built when a grouped grid will consume it
+            sstats.update(visit_sharing(visit_np, pad_block=pad_block),
+                          steps=Q * T)
+            board_ok = (Q + 1) * T * blk <= BLOCKED_MAX_BOARD_SLOTS
+            row_bytes = m * luts.shape[-1] * (
+                4 if lut_dtype == "float32" else 2 if lut_dtype == "bfloat16"
+                else 5)  # int8 entries + their share of the f32 scales
 
-        def fits(qb):
-            # the scatter board bounds the twins, panel + SMEM the kernels
-            if backend == "jnp":
-                return board_ok
-            return _kernel_grouped_fits(sstats["pairs"], sstats["blocks"],
-                                        qb, row_bytes)
-        if mode != "auto":
-            grid = mode
-        elif autotune is False:
-            # PR-8 constant heuristic, kept as the untuned escape hatch
-            if (Q >= BLOCKED_MIN_QUERIES and fits(eff_qblk)
-                    and sstats["sharing"] >= BLOCKED_MIN_SHARING):
-                grid = "blocked"
-        else:
-            tuner = LEDGER if autotune is None else autotune
-            tkey = (backend, m, luts.shape[-1], blk, lut_dtype)
-            entry = tuner.lookup(tkey)
-            if entry is not None:
-                sstats["crossover"] = entry["crossover"]
-                e_qblk = entry["qblk"] if qblk is None else qblk
-                if (sstats["pairs"] > 0 and fits(e_qblk)
-                        and sstats["sharing"] >= entry["crossover"]):
-                    grid = entry["grouped_mode"]
-                    eff_qblk = e_qblk
-            elif sstats["pairs"] > 0:
-                probe_cfg = tuner.next_probe(tkey)
-                p_qblk = (probe_cfg[1] or eff_qblk) if probe_cfg else 0
-                if probe_cfg is not None and fits(p_qblk):
-                    grid = probe_cfg[0]
-                    eff_qblk = p_qblk
-                    sstats["probe"] = True
-                else:
-                    probe_cfg = None  # probes wait for a batch that fits
+            def fits(qb):
+                # the scatter board bounds the twins, panel + SMEM the kernels
+                if backend == "jnp":
+                    return board_ok
+                return _kernel_grouped_fits(sstats["pairs"], sstats["blocks"],
+                                            qb, row_bytes)
+            if mode != "auto":
+                grid = mode
+            elif autotune is False:
+                # PR-8 constant heuristic, kept as the untuned escape hatch
+                if (Q >= BLOCKED_MIN_QUERIES and fits(eff_qblk)
+                        and sstats["sharing"] >= BLOCKED_MIN_SHARING):
+                    grid = "blocked"
+            else:
+                tuner = LEDGER if autotune is None else autotune
+                tkey = (backend, m, luts.shape[-1], blk, lut_dtype)
+                entry = tuner.lookup(tkey)
+                if entry is not None:
+                    sstats["crossover"] = entry["crossover"]
+                    e_qblk = entry["qblk"] if qblk is None else qblk
+                    if (sstats["pairs"] > 0 and fits(e_qblk)
+                            and sstats["sharing"] >= entry["crossover"]):
+                        grid = entry["grouped_mode"]
+                        eff_qblk = e_qblk
+                elif sstats["pairs"] > 0:
+                    probe_cfg = tuner.next_probe(tkey)
+                    p_qblk = (probe_cfg[1] or eff_qblk) if probe_cfg else 0
+                    if probe_cfg is not None and fits(p_qblk):
+                        grid = probe_cfg[0]
+                        eff_qblk = p_qblk
+                        sstats["probe"] = True
+                    else:
+                        probe_cfg = None  # probes wait for a batch that fits
+            sharing_span.attrs["sharing"] = sstats["sharing"]
     built = None
     if grid != "per_query":
-        built = _build_schedule_cached(visit_np, eff_qblk, pad_block,
-                                       sched_cache, sched_key, Q, T)
+        with obs.span("ivf.schedule") as sp:
+            built = _build_schedule_cached(visit_np, eff_qblk, pad_block,
+                                           sched_cache, sched_key, Q, T)
+            sp.attrs["groups"] = built["groups"]
         sstats["groups"] = built["groups"]
         sstats["qblk"] = eff_qblk
     sstats["mode"] = grid
@@ -783,21 +797,24 @@ def ivf_adc_topk(bucket_codes, bucket_ids, visit, luts, *, k: int,
             built["st"], visit.astype(jnp.int32), lj, coarse, k=k,
             steps_per_probe=steps_per_probe, lut_dtype=ld)
 
-    if probe_cfg is not None:
-        # measured probe: a warm-up call absorbs compiles/gathers, then one
-        # timed call (the schedule is prebuilt — the host sort is identical
-        # across grouped candidates, so it cancels out of the comparison)
-        jax.block_until_ready(_run(grid))
-        t0 = time.perf_counter()
-        s, i = _run(grid)
-        jax.block_until_ready((s, i))
-        tuner.record(tkey, probe_cfg, sstats["sharing"],
-                     time.perf_counter() - t0)
-        entry = tuner.lookup(tkey)
-        if entry is not None and stats is not None:
-            stats["crossover"] = entry["crossover"]
-    else:
-        s, i = _run(grid)
+    with obs.span("ivf.adc", grid=grid, steps=sstats["steps"],
+                  real_steps=sstats["pairs"], probe=sstats["probe"]):
+        if probe_cfg is not None:
+            # measured probe: a warm-up call absorbs compiles/gathers, then
+            # one timed call (the schedule is prebuilt — the host sort is
+            # identical across grouped candidates, so it cancels out of the
+            # comparison)
+            jax.block_until_ready(_run(grid))
+            t0 = time.perf_counter()
+            s, i = _run(grid)
+            jax.block_until_ready((s, i))
+            tuner.record(tkey, probe_cfg, sstats["sharing"],
+                         time.perf_counter() - t0)
+            entry = tuner.lookup(tkey)
+            if entry is not None and stats is not None:
+                stats["crossover"] = entry["crossover"]
+        else:
+            s, i = _run(grid)
     bad = s <= 0.5 * NEG_INF
     return jnp.where(bad, -jnp.inf, s), jnp.where(bad, -1, i)
 

@@ -41,7 +41,8 @@ Threads and queues
     assembles the next batch while the device scores this one.
   * **The completer thread** drains the inflight queue, performs the
     batch's one host sync (``jax.device_get``), scatters per-request
-    results into their futures, and records enqueue->result latencies.
+    results into their futures, and records each request's
+    enqueue->result span (``serve.request``, in ``repro.obs``).
     ``max_inflight`` is an exact device-pipeline bound enforced by a slot
     semaphore: the batcher takes a slot before each dispatch and the
     completer returns it after the host sync, so at most ``max_inflight``
@@ -62,8 +63,15 @@ machinery the pump front proved out.
 
 ``latency_stats`` adds the serving gauges to the shared summary:
 ``queue_depth`` / ``queue_depth_max`` (bounded-queue occupancy),
-``rejected`` (backpressure refusals), and ``inflight`` (batches dispatched
-but not yet synced).
+``rejected`` (backpressure refusals), ``inflight`` (batches dispatched
+but not yet synced), and ``rows_dispatched`` / ``rows_real`` (plan-bucket
+rows sent to ``db.query`` and the real queries among them).
+
+Spans (``repro.obs``; one batch id joins them): the batcher's
+``serve.slot_wait`` (waiting for an inflight slot), ``serve.batch_wait``
+(filling the batch), ``serve.dispatch`` (assemble, encode, ``db.query``);
+the completer's ``serve.complete`` (host sync, scatter, resolve) and one
+``serve.request`` per read, from ``submit`` to its resolve.
 """
 from __future__ import annotations
 
@@ -78,11 +86,12 @@ from typing import Callable, List, Optional
 import jax
 import numpy as np
 
+from repro import obs
 from repro.core.db import PLAN_BUCKETS
 from repro.serve.engine import (WRITE_KINDS, Request, WriteRequest,
                                 apply_db_write, assemble_queries, bucket_of,
                                 query_kwargs, read_group,
-                                summarize_latencies)
+                                request_latencies_ms, summarize_latencies)
 
 
 class BackpressureError(RuntimeError):
@@ -229,8 +238,10 @@ class AsyncQueryEngine:
         self._idle = threading.Condition(self._lock)
         self._outstanding = 0  # accepted jobs whose future hasn't resolved
         self._rid = itertools.count()  # lock-free: count() is atomic enough
-        self.latencies_ms: List[float] = []
+        self.front = obs.new_id()  # tags this front's serve.request spans
         self.writes_applied = 0
+        self.rows_dispatched = 0  # plan-bucket rows sent to db.query
+        self.rows_real = 0        # ... of which real queries
         self.rejected = 0
         self.queue_depth_max = 0
         self._closed = False
@@ -466,26 +477,32 @@ class AsyncQueryEngine:
                         and time.perf_counter() >= deadline):
                     raise
 
-    def _dispatch(self, batch: List[Request]) -> None:
-        """Assemble + encode + dispatch one read micro-batch. The caller
-        must hold an inflight slot; it travels with the batch and the
-        completer releases it after the host sync (or the except path
-        here, if dispatch never reaches the device). db.query's async
-        dispatch returns device arrays immediately, so the batcher is
-        back to accepting while the device scores."""
-        k = max(r.k for r in batch)
-        q = assemble_queries(batch, bucket_of(len(batch), self.BUCKETS))
-        try:
-            qv = self.encoder(q) if self.encoder is not None else q
-            scores, ids = self.db.query(qv, k=k,
-                                        **query_kwargs(batch, len(q)))
-        except Exception as e:
-            self._slots.release()
-            for r in batch:
-                r.future.set_exception(e)
-            self._resolve_one(len(batch))
-            return
-        self._inflight.put((batch, scores, ids))
+    def _dispatch(self, batch: List[Request], bid: int) -> None:
+        """Assemble + encode + dispatch one read micro-batch (batch id
+        ``bid``). The caller must hold an inflight slot; it travels with
+        the batch and the completer releases it after the host sync (or
+        the except path here, if dispatch never reaches the device).
+        db.query's async dispatch returns device arrays immediately, so
+        the batcher is back to accepting while the device scores."""
+        rows = bucket_of(len(batch), self.BUCKETS)
+        with obs.span("serve.dispatch", batch=bid, rows=rows,
+                      real=len(batch)):
+            k = max(r.k for r in batch)
+            q = assemble_queries(batch, rows)
+            try:
+                qv = self.encoder(q) if self.encoder is not None else q
+                scores, ids = self.db.query(qv, k=k,
+                                            **query_kwargs(batch, len(q)))
+            except Exception as e:
+                self._slots.release()
+                for r in batch:
+                    r.future.set_exception(e)
+                self._resolve_one(len(batch))
+                return
+        with self._lock:
+            self.rows_dispatched += len(q)
+            self.rows_real += len(batch)
+        self._inflight.put((batch, bid, scores, ids))
 
     def _batch_loop(self) -> None:
         wait_s = self.max_wait_ms * 1e-3
@@ -509,40 +526,44 @@ class AsyncQueryEngine:
             # wait for the device pipeline to free, arrivals keep landing
             # in the queue and ride along in THIS batch — the adaptive
             # batch-size behavior that keeps latency flat under load
-            self._slots.acquire()
+            with obs.span("serve.slot_wait"):
+                self._slots.acquire()
+            bid = obs.new_id()
             batch = [job]
             group = read_group(job)  # filter/hybrid batch-compat key
             deadline = None  # lazily armed: saturated queues never sleep
             closer = None  # the write (or sentinel) that closed the batch
-            while len(batch) < self.max_batch and not self._discard.is_set():
-                if not pending:  # bulk-pop: one lock per refill, not per job
-                    pending.extend(
-                        self._requests.pop_ready(self.max_batch - len(batch)))
-                if pending:
-                    nxt = pending.popleft()
-                else:
-                    if deadline is None:
-                        deadline = time.perf_counter() + wait_s
-                    remaining = deadline - time.perf_counter()
-                    if remaining <= 0:
+            with obs.span("serve.batch_wait", batch=bid):
+                while (len(batch) < self.max_batch
+                       and not self._discard.is_set()):
+                    if not pending:  # bulk-pop: one lock per refill
+                        pending.extend(self._requests.pop_ready(
+                            self.max_batch - len(batch)))
+                    if pending:
+                        nxt = pending.popleft()
+                    else:
+                        if deadline is None:
+                            deadline = time.perf_counter() + wait_s
+                        remaining = deadline - time.perf_counter()
+                        if remaining <= 0:
+                            break
+                        try:
+                            nxt = self._get_job(remaining)
+                        except queue.Empty:
+                            break
+                    if nxt is _SENTINEL:
+                        done = True
                         break
-                    try:
-                        nxt = self._get_job(remaining)
-                    except queue.Empty:
+                    if isinstance(nxt, WriteRequest):
+                        closer = nxt  # a write CLOSES the batch: reads ahead
+                        break  # of it must not observe it (read-your-writes)
+                    if read_group(nxt) != group:
+                        # a different (predicate, alpha) group also closes
+                        # the batch; the read stays at the head for the next
+                        pending.appendleft(nxt)
                         break
-                if nxt is _SENTINEL:
-                    done = True
-                    break
-                if isinstance(nxt, WriteRequest):
-                    closer = nxt  # a write CLOSES the batch: reads ahead of
-                    break         # it must not observe it (read-your-writes)
-                if read_group(nxt) != group:
-                    # a different (predicate, alpha) group also closes the
-                    # batch; the read stays at the head for the next one
-                    pending.appendleft(nxt)
-                    break
-                batch.append(nxt)
-            self._dispatch(batch)
+                    batch.append(nxt)
+            self._dispatch(batch, bid)
             if closer is not None:
                 if self._discard.is_set():
                     closer.future.cancel()
@@ -564,7 +585,7 @@ class AsyncQueryEngine:
 
         def flush(batch):
             self._slots.acquire()
-            self._dispatch(batch)
+            self._dispatch(batch, obs.new_id())
 
         batch: List[Request] = []
         for job in jobs:
@@ -595,27 +616,26 @@ class AsyncQueryEngine:
             item = self._inflight.get()
             if item is _SENTINEL:
                 return
-            batch, scores, ids = item
-            try:
-                scores, ids = jax.device_get((scores, ids))
-            except Exception as e:
-                self._slots.release()  # device done (badly): slot frees
-                for r in batch:
-                    r.future.set_exception(e)
+            batch, bid, scores, ids = item
+            with obs.span("serve.complete", batch=bid):
+                try:
+                    scores, ids = jax.device_get((scores, ids))
+                except Exception as e:
+                    self._slots.release()  # device done (badly): slot frees
+                    for r in batch:
+                        r.future.set_exception(e)
+                    self._resolve_one(len(batch))
+                    continue
+                self._slots.release()  # host sync done: batcher may dispatch
+                t_ns = time.perf_counter_ns()
+                for i, r in enumerate(batch):
+                    r.result = (scores[i, : r.k], ids[i, : r.k])
+                    r.t_done = t_ns * 1e-9
+                    obs.record("serve.request", round(r.t_enqueue * 1e9),
+                               t_ns, front=self.front, rid=r.rid, batch=bid)
+                for r in batch:  # resolve AFTER recording: stats can't lag
+                    r.future.set_result(r.result)
                 self._resolve_one(len(batch))
-                continue
-            self._slots.release()  # host sync done: the batcher may dispatch
-            t = time.perf_counter()
-            lats = []
-            for i, r in enumerate(batch):
-                r.result = (scores[i, : r.k], ids[i, : r.k])
-                r.t_done = t
-                lats.append((t - r.t_enqueue) * 1e3)
-            with self._lock:
-                self.latencies_ms.extend(lats)
-            for r in batch:  # resolve AFTER recording: stats can't lag results
-                r.future.set_result(r.result)
-            self._resolve_one(len(batch))
 
     # ---------------------------------------------------------------- stats
     def latency_stats(self) -> dict:
@@ -625,15 +645,19 @@ class AsyncQueryEngine:
         ``QueryEngine.latency_stats``) plus the continuous-batching gauges:
         ``queue_depth`` (now), ``queue_depth_max`` (high-water mark),
         ``rejected`` (backpressure refusals), ``inflight`` (batches
-        dispatched, not yet synced). Thread-safe; callable while serving."""
+        dispatched, not yet synced), ``rows_dispatched`` / ``rows_real``
+        (bucket rows sent to ``db.query`` / real queries among them).
+        Thread-safe; callable while serving."""
+        lats = request_latencies_ms(self.front)
         with self._lock:
-            lats = list(self.latencies_ms)
             extra = {"queue_depth": self._requests.qsize()
                      + len(self._pending),
                      "queue_depth_max": self.queue_depth_max,
                      "rejected": self.rejected,
                      "inflight": self._inflight.qsize(),
-                     "durable_pending": self._durable_pending}
+                     "durable_pending": self._durable_pending,
+                     "rows_dispatched": self.rows_dispatched,
+                     "rows_real": self.rows_real}
             writes = self.writes_applied
         if not lats and not writes and not self.rejected:
             return {}

@@ -44,8 +44,10 @@ a plan miss on the next query via the shared ledger's ``plan_generation``.
 Both fronts route writes through ``VectorDB.apply_write`` — the single
 write entry point in ``repro.core.db`` — so write dispatch has one body.
 
-``latency_stats`` reports enqueue->result p50/p99 per request plus the
-DB's plan-cache counters AND its mutation counters
+``latency_stats`` reports enqueue->result p50/p99 over the front's
+``serve.request`` spans still held by the bounded record of
+``repro.obs`` (one per resolved read), plus the DB's plan-cache
+counters AND its mutation counters
 (inserts/deletes/upserts/compactions, from the engine's
 ``mutation_stats``), so a serving run can prove it stopped retracing
 (misses stay flat while hits grow) and show the write mix it absorbed. The
@@ -63,6 +65,7 @@ from typing import Callable, Dict, List, Optional
 import jax
 import numpy as np
 
+from repro import obs
 from repro.core.db import PLAN_BUCKETS
 
 WRITE_KINDS = ("insert", "delete", "upsert", "compact")
@@ -162,12 +165,19 @@ def apply_db_write(db, kind: str, vectors=None, ids=None):
     raise ValueError(f"unknown write kind {kind!r}; have {WRITE_KINDS}")
 
 
+def request_latencies_ms(front: int) -> List[float]:
+    """Enqueue->result ms of one front's reads, from its ``serve.request``
+    spans that the bounded record still holds."""
+    return [(s.t1 - s.t0) * 1e-6 for s in obs.spans()
+            if s.name == "serve.request" and s.attrs.get("front") == front]
+
+
 def summarize_latencies(latencies_ms, writes_applied: int, db,
                         extra: Optional[dict] = None) -> Dict[str, float]:
-    """The one ``latency_stats`` body: enqueue->result percentiles +
-    the DB's plan-cache and mutation counters (when the front keeps them).
-    ``extra`` lets the async front append its queue-depth/backpressure
-    gauges without duplicating this."""
+    """The one ``latency_stats`` body: enqueue->result percentiles (of
+    ``request_latencies_ms``) + the DB's plan-cache and mutation counters
+    (when the front keeps them). ``extra`` lets the async front append its
+    queue-depth/backpressure gauges without duplicating this."""
     if not latencies_ms and not writes_applied and not extra:
         return {}
     stats = {"engine": getattr(db, "engine_name", "?")}
@@ -205,6 +215,10 @@ def summarize_latencies(latencies_ms, writes_applied: int, db,
             stats["adc_sched_cache_misses"] = int(adc["sched_cache_misses"])
         stats["adc_sharing_factor"] = float(adc["sharing_sum"] / b)
         stats["adc_effective_nprobe"] = float(adc["eff_nprobe_sum"] / b)
+        # grid steps of the batches whose visit table came to the host,
+        # and those that visit a real (not the all-pad) block
+        stats["adc_steps"] = int(adc["steps"])
+        stats["adc_real_steps"] = int(adc["real_steps"])
     flt = getattr(db, "filter_stats", None)
     if flt is not None:
         # filtered/hybrid telemetry: batches that carried a predicate,
@@ -241,7 +255,7 @@ class QueryEngine:
         self.queue: List = []  # Requests and WriteRequests, arrival order
         self.done: Dict[int, object] = {}
         self._next_id = 0
-        self.latencies_ms: List[float] = []
+        self.front = obs.new_id()  # tags this front's serve.request spans
         self.writes_applied = 0
 
     def submit(self, query: np.ndarray, k: int = 10, *,
@@ -313,12 +327,13 @@ class QueryEngine:
         qv = self.encoder(q) if self.encoder is not None else q
         scores, ids = self.db.query(qv, k=k, **query_kwargs(take, len(q)))
         scores, ids = jax.device_get((scores, ids))  # the batch's one host sync
-        t = time.perf_counter()
+        t_ns, batch = time.perf_counter_ns(), obs.new_id()
         for i, r in enumerate(take):
             r.result = (scores[i, : r.k], ids[i, : r.k])
-            r.t_done = t
+            r.t_done = t_ns * 1e-9
             self.done[r.rid] = r
-            self.latencies_ms.append((t - r.t_enqueue) * 1e3)
+            obs.record("serve.request", round(r.t_enqueue * 1e9), t_ns,
+                       front=self.front, rid=r.rid, batch=batch)
         return n
 
     def drain(self) -> int:
@@ -339,5 +354,5 @@ class QueryEngine:
         """Enqueue->result p50/p99/mean per served read + the DB front's
         plan-cache (``plan_hits``/``plan_misses``) and mutation
         (``write_*``) counters. Empty dict before any request resolves."""
-        return summarize_latencies(self.latencies_ms, self.writes_applied,
-                                   self.db)
+        return summarize_latencies(request_latencies_ms(self.front),
+                                   self.writes_applied, self.db)
