@@ -162,7 +162,8 @@ def sharded_ivf_pq_search(bucket_codes, bucket_ids, visit, luts, coarse, *,
     block falls in its range (localized to its (blocks_per_shard + 1, blk)
     slab) and retargets every other step — off-shard probes AND -1 tails —
     at its local all-pad block, so they knock out on id without any score
-    surgery. The local bucket-resident ADC dispatch (Pallas ivf_adc kernel
+    surgery, and the per-query kernel, told that block, skips them. The
+    local bucket-resident ADC dispatch (Pallas ivf_adc kernel
     per shard on TPU, jnp twin elsewhere) then runs unchanged, local ids
     are already global corpus rows (bucket_ids store them), and the same
     local-top-k + hierarchical all-gather merge as the flat/pq paths
@@ -197,7 +198,8 @@ def sharded_ivf_pq_search(bucket_codes, bucket_ids, visit, luts, coarse, *,
         s, i = kops.ivf_adc_topk(c_blk, id_blk, v_loc, luts_rep, k=kk,
                                  coarse=coarse_rep,
                                  steps_per_probe=steps_per_probe,
-                                 use_kernel=use_kernel, lut_dtype=lut_dtype)
+                                 use_kernel=use_kernel, lut_dtype=lut_dtype,
+                                 pad_block=blocks_per_shard)
         return _merge_local_topk(s, i, k=k, axes=axes,
                                  hierarchical=hierarchical)
 

@@ -33,6 +33,15 @@ metric geometry:
   l2:  per-(query, probe) LUTs on t = q - centroid_p (4-D luts input);
        coarse[q, p] = 0.
 
+Given the caller's ``pad_block``, a program whose step visits the shared
+all-pad block scores and merges nothing: its slots all hold id -1, so it
+could only fold NEG_INF / -1 into a scoreboard that starts at NEG_INF / -1
+and keeps its earlier entries on ties. Scores and the ids of every entry
+above NEG_INF / 2 come out bit-identical to scoring the step; only the ids
+under knocked-out (NEG_INF) entries may differ, and the ops.py dispatcher
+normalizes those to -1. The run of pad steps keeps one block index, so the
+pipeline already fetches the pad block once; the skip removes the compute.
+
 ``coarse`` doubles as a probe knockout: callers mask a whole probe by
 adding NEG_INF to its coarse term; pad slots (id -1) knock out in-kernel.
 The -1 sentinel is also how PREDICATE FILTERS reach this kernel
@@ -101,12 +110,14 @@ def _probe_coarse(crow, p):
 
 
 def _ivf_adc_kernel(visit_ref, c_ref, id_ref, l_ref, coarse_ref, *refs,
-                    n_steps: int, spp: int, k: int, ksub: int, int8: bool):
+                    n_steps: int, spp: int, k: int, ksub: int, int8: bool,
+                    pad_block):
     if int8:
         sc_ref, s_out, i_out, bs_ref, bi_ref = refs
     else:
         sc_ref = None
         s_out, i_out, bs_ref, bi_ref = refs
+    q = pl.program_id(0)
     t = pl.program_id(1)
 
     @pl.when(t == 0)
@@ -114,17 +125,24 @@ def _ivf_adc_kernel(visit_ref, c_ref, id_ref, l_ref, coarse_ref, *refs,
         bs_ref[...] = jnp.full_like(bs_ref, NEG_INF)
         bi_ref[...] = jnp.full_like(bi_ref, -1)
 
-    ids = id_ref[0]        # (1, blk) int32 global row ids, -1 = pad slot
-    s = adc_block_scores(l_ref[0], c_ref[0], ksub,
-                         None if sc_ref is None else sc_ref[0])  # (1, blk)
-    # coarse carries the metric's centroid term AND the caller's probe
-    # knockout (NEG_INF for masked probes); pad slots knock out on id
-    s = s + _probe_coarse(coarse_ref[0], t // spp)
-    s = jnp.where(ids >= 0, s, NEG_INF)
+    def _score():
+        ids = id_ref[0]    # (1, blk) int32 global row ids, -1 = pad slot
+        s = adc_block_scores(l_ref[0], c_ref[0], ksub,
+                             None if sc_ref is None else sc_ref[0])
+        # coarse carries the metric's centroid term AND the caller's probe
+        # knockout (NEG_INF for masked probes); pad slots knock out on id
+        s = s + _probe_coarse(coarse_ref[0], t // spp)
+        s = jnp.where(ids >= 0, s, NEG_INF)
 
-    comb_s = jnp.concatenate([bs_ref[...], s], axis=1)
-    comb_i = jnp.concatenate([bi_ref[...], ids], axis=1)
-    bs_ref[...], bi_ref[...] = _select_topk(comb_s, comb_i, k)
+        comb_s = jnp.concatenate([bs_ref[...], s], axis=1)
+        comb_i = jnp.concatenate([bi_ref[...], ids], axis=1)
+        bs_ref[...], bi_ref[...] = _select_topk(comb_s, comb_i, k)
+
+    if pad_block is None:
+        _score()
+    else:
+        # a step on the shared all-pad block could only merge NEG_INF / -1
+        pl.when(visit_ref[q * n_steps + t] != pad_block)(_score)
 
     @pl.when(t == n_steps - 1)
     def _finalize():
@@ -149,10 +167,10 @@ SMEM_VISIT_BYTES = 512 << 10
 
 @functools.partial(jax.jit,
                    static_argnames=("k", "steps_per_probe", "interpret",
-                                    "lut_dtype"))
+                                    "lut_dtype", "pad_block"))
 def ivf_adc(bucket_codes, bucket_ids, visit, luts, coarse, *, k: int,
             steps_per_probe: int = 1, interpret: bool = False,
-            lut_dtype: str = "float32"):
+            lut_dtype: str = "float32", pad_block=None):
     """bucket_codes: (B, blk, m) int32; bucket_ids: (B, blk) int32 (-1
     pad); visit: (Q, T) int32 block ids, T = nprobe * steps_per_probe;
     luts: (Q, m, ksub) f32 (shared, dot) or (Q, nprobe, m, ksub) f32
@@ -165,6 +183,10 @@ def ivf_adc(bucket_codes, bucket_ids, visit, luts, coarse, *, k: int,
     with pad slots (id -1) and anything the caller NEG_INF'd in ``coarse``
     knocked to NEG_INF. Unfilled scoreboard slots come back NEG_INF / -1
     (the ops.py dispatcher normalizes them to -inf / -1).
+
+    ``pad_block`` (static int, optional) names the shared all-pad block:
+    steps that visit it score and merge nothing. Without it every step
+    scores its block.
     """
     B, blk, m = bucket_codes.shape
     Q, T = visit.shape
@@ -206,7 +228,8 @@ def ivf_adc(bucket_codes, bucket_ids, visit, luts, coarse, *, k: int,
                 pl.BlockSpec((1, 1, m), lambda q, t, v: (row(q, t), 0, 0)))
             args.append(scales_c[0].reshape(qc * P, 1, m))
         kernel = functools.partial(_ivf_adc_kernel, n_steps=T, spp=spp, k=k,
-                                   ksub=ksub, int8=bool(scales_c))
+                                   ksub=ksub, int8=bool(scales_c),
+                                   pad_block=pad_block)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(qc, T),

@@ -632,7 +632,7 @@ def ivf_adc_topk(bucket_codes, bucket_ids, visit, luts, *, k: int,
     schedule's per-block RUNS so each distinct block is fetched once for
     the whole batch. All grids are bit-identical per backend on the same
     visit table (forced grouped modes raise under jit — the schedule is
-    host-built).
+    host-built). The per-query kernel skips the steps on ``pad_block``.
 
     'auto' resolves the grid from the MEASURED online autotuner
     (``repro.kernels.autotune``): the first batches of each
@@ -767,7 +767,8 @@ def ivf_adc_topk(bucket_codes, bucket_ids, visit, luts, *, k: int,
                     bucket_codes, bids, visit.astype(jnp.int32), luts,
                     coarse, k=k, steps_per_probe=steps_per_probe,
                     interpret=_auto_interpret(interpret),
-                    lut_dtype=lut_dtype)
+                    lut_dtype=lut_dtype,
+                    pad_block=None if pad_block is None else int(pad_block))
             lj, ld = _jnp_luts()
             return ivf_adc_topk_jnp(
                 bucket_codes, bids, visit.astype(jnp.int32), lj, coarse,

@@ -137,6 +137,46 @@ def test_distributed_ivf_pq_int8_luts():
     """, n_dev=2)
 
 
+def test_distributed_ivf_pq_pad_skip_identical():
+    """Each shard names its local pad row to the per-query kernel, which
+    skips the off-shard and tail steps retargeted there: the answers equal
+    the kernel's that scores every step, and the jnp twin's."""
+    run_spmd("""
+        import jax, numpy as np
+        from repro.core import DistributedIVFPQ
+        from repro.kernels import ops as kops
+        mesh = jax.make_mesh((2,), ('data',))
+        rng = np.random.default_rng(2)
+        centers = rng.normal(size=(10, 16)).astype(np.float32) * 2.0
+        corpus = (centers[rng.integers(0, 10, 400)]
+                  + rng.normal(size=(400, 16)).astype(np.float32))
+        q = corpus[:4] + 0.01 * rng.normal(size=(4, 16)).astype(np.float32)
+        seen = []
+        real = kops.ivf_adc_topk
+
+        def every_step(*a, **kw):
+            seen.append(kw.pop('pad_block'))
+            return real(*a, **kw)
+
+        for metric in ['cosine', 'l2']:
+            kw = dict(metric=metric, nprobe=4, n_clusters=10)
+            ker = DistributedIVFPQ(mesh, use_kernel=True, **kw).load(corpus)
+            twin = DistributedIVFPQ(mesh, use_kernel=False, **kw).load(corpus)
+            s0, i0 = map(np.asarray, ker.query(q, k=5))
+            s2, i2 = map(np.asarray, twin.query(q, k=5))
+            kops.ivf_adc_topk = every_step
+            jax.clear_caches()
+            s1, i1 = map(np.asarray, ker.query(q, k=5))
+            kops.ivf_adc_topk = real
+            jax.clear_caches()
+            assert seen[-1] == ker.blocks_per_shard, (seen, metric)
+            assert (i0 == i1).all() and (s0 == s1).all(), metric
+            assert (i0 == i2).all() and (s0 == s2).all(), metric
+            assert (i0 >= 0).all(), metric
+        print('OK')
+    """, n_dev=2)
+
+
 def test_two_level_search_matches_flat():
     run_spmd("""
         import jax, jax.numpy as jnp, numpy as np

@@ -11,8 +11,11 @@ import pytest
 from repro.core import VectorDB, build_block_lists
 from repro.core.ivf import build_buckets
 from repro.kernels import ivf_adc_topk, ivf_adc_topk_jnp, quantize_lut_int8
+from repro.kernels import ivf_adc as ivf_mod
 from repro.kernels import ref as R
 from repro.kernels.ivf_adc import ivf_adc
+from repro.kernels.ops import mask_allowed_ids
+from repro.kernels.topk_distance import NEG_INF
 
 
 def _clustered(rng, n, d, n_clusters, scale=2.0):
@@ -111,6 +114,125 @@ def test_ivf_adc_twin_matches_kernel(rng, lut_dtype):
     np.testing.assert_array_equal(np.asarray(i0), np.asarray(i1))
     np.testing.assert_allclose(np.asarray(s0), np.asarray(s1), atol=1e-4,
                                rtol=1e-4)
+
+
+# ------------------------------------------------------------ pad-step skip
+
+PAD_SKIP_CASES = ("ragged", "all_pad", "adaptive", "allowed", "tombstoned",
+                  "smem_chunks")
+
+
+def _pad_skip_problem(rng, case, Q=5, C=12, blk=8, m=8, ksub=32, nprobe=4):
+    """A per-query grid walk with many pad steps: one long list sets
+    steps_per_probe, the other lists are short and ragged, one is empty.
+    ``case`` layers one more way a step or slot knocks out on top."""
+    n = 100
+    assign = rng.integers(0, C, n)
+    assign[:40] = 0
+    assign[assign == 2] = 3
+    slots, bstart, bcnt, spp = build_block_lists(assign, C, blk=blk)
+    pad = slots.shape[0] - 1
+    codes = rng.integers(0, ksub, (slots.shape[0], blk, m)).astype(np.int32)
+    probe = np.stack([rng.choice(C, nprobe, replace=False)
+                      for _ in range(Q)]).astype(np.int32)
+    probe[0, 1] = 2  # the empty list: a probe of pad steps only
+    visit = np.array(_expand_visit(probe, bstart, bcnt, spp, pad + 1))
+    luts = rng.normal(size=(Q, m, ksub)).astype(np.float32)
+    coarse = rng.normal(size=(Q, nprobe)).astype(np.float32)
+    if case == "all_pad":
+        visit[:] = pad
+    elif case == "adaptive":
+        # as _ivf_probe_stage masks: steps to the pad block, coarse NEG_INF
+        active = rng.random((Q, nprobe)) < 0.5
+        active[:, 0] = True
+        visit = np.where(np.repeat(active, spp, axis=1), visit, pad)
+        coarse = np.where(active, coarse, NEG_INF).astype(np.float32)
+    elif case == "allowed":
+        slots = np.asarray(mask_allowed_ids(jnp.asarray(slots),
+                                            jnp.asarray(rng.random(n) < 0.3)))
+    elif case == "tombstoned":
+        slots = np.where(rng.random(slots.shape) < 0.4, -1, slots)
+        slots[bstart[0]] = -1  # a whole real block deleted, still visited
+    return (jnp.asarray(codes), jnp.asarray(slots), jnp.asarray(visit),
+            jnp.asarray(luts), jnp.asarray(coarse), spp, pad)
+
+
+def _live_ids(s, i):
+    """The ids the dispatcher serves: -1 under knocked-out scores."""
+    s, i = np.asarray(s), np.asarray(i)
+    return np.where(s > 0.5 * NEG_INF, i, -1)
+
+
+@pytest.mark.parametrize("lut_dtype", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("case", PAD_SKIP_CASES)
+def test_ivf_adc_pad_skip_bit_identical(rng, monkeypatch, case, lut_dtype):
+    """The per-query kernel told the pad block skips its steps and returns
+    bit-identical raw scores, and identical ids wherever a score is above
+    the knockout, to the same kernel scoring every step and to the jnp
+    twin; the served (normalized) answers are identical outright. k
+    exceeds some queries' candidates, so knocked-out slots are compared
+    too. ``smem_chunks`` shrinks the SMEM budget so the wrapper's
+    query-chunk loop (a padded last chunk included) carries the skip."""
+    codes, slots, visit, luts, coarse, spp, pad = _pad_skip_problem(rng, case)
+    Q, T = visit.shape
+    if case == "smem_chunks":
+        monkeypatch.setattr(ivf_mod, "SMEM_VISIT_BYTES", 4 * T * 2)
+        ivf_adc.clear_cache()  # a cached unchunked trace would hide the loop
+        assert ivf_mod.SMEM_VISIT_BYTES // (4 * T) < Q
+    kw = dict(k=40, steps_per_probe=spp, lut_dtype=lut_dtype)
+    s0, i0 = ivf_adc(codes, slots, visit, luts, coarse, interpret=True, **kw)
+    s1, i1 = ivf_adc(codes, slots, visit, luts, coarse, interpret=True,
+                     pad_block=pad, **kw)
+    sj, ij = ivf_adc_topk_jnp(codes, slots, visit, luts, coarse, **kw)
+    if case == "smem_chunks":
+        ivf_adc.clear_cache()
+    np.testing.assert_array_equal(np.asarray(s1), np.asarray(s0))
+    np.testing.assert_array_equal(_live_ids(s1, i1), _live_ids(s0, i0))
+    np.testing.assert_array_equal(np.asarray(s1), np.asarray(sj))
+    np.testing.assert_array_equal(_live_ids(s1, i1), _live_ids(sj, ij))
+    if case == "all_pad":
+        assert np.all(np.asarray(s1) == NEG_INF)
+        assert np.all(np.asarray(i1) == -1)
+    else:
+        assert (_live_ids(s1, i1) >= 0).any()
+        assert (_live_ids(s1, i1) == -1).any()  # some knockout fires
+    served = [ivf_adc_topk(codes, slots, visit, luts, coarse=coarse,
+                           mode="per_query", use_kernel=use_kernel,
+                           pad_block=pb, **kw)
+              for use_kernel, pb in ((True, pad), (True, None), (False, pad))]
+    for s, i in served[1:]:
+        np.testing.assert_array_equal(np.asarray(s), np.asarray(served[0][0]))
+        np.testing.assert_array_equal(np.asarray(i), np.asarray(served[0][1]))
+
+
+def test_ivf_adc_pad_skip_keys_on_the_pad_block(rng):
+    """Plant live ids and non-zero codes in the pad row: the kernel told
+    the pad block, directly or through the dispatcher, returns exactly
+    what the clean layout returns, and the kernel that scores every step
+    serves the planted rows. So the skip covers every pad step and nothing
+    else."""
+    codes, slots, visit, luts, coarse, spp, pad = _pad_skip_problem(
+        rng, "ragged")
+    blk, m = codes.shape[1:]
+    planted = 1000 + np.arange(blk, dtype=np.int32)
+    dirty_slots = slots.at[pad].set(jnp.asarray(planted))
+    dirty_codes = codes.at[pad].set(
+        jnp.asarray(rng.integers(1, 32, (blk, m)).astype(np.int32)))
+    kw = dict(k=64, steps_per_probe=spp, interpret=True)
+    clean = ivf_adc(codes, slots, visit, luts, coarse, pad_block=pad, **kw)
+    skip = ivf_adc(dirty_codes, dirty_slots, visit, luts, coarse,
+                   pad_block=pad, **kw)
+    every = ivf_adc(dirty_codes, dirty_slots, visit, luts, coarse, **kw)
+    np.testing.assert_array_equal(np.asarray(skip[0]), np.asarray(clean[0]))
+    np.testing.assert_array_equal(np.asarray(skip[1]), np.asarray(clean[1]))
+    assert not np.isin(_live_ids(*skip), planted).any()
+    assert np.isin(_live_ids(*every), planted).any(axis=1).all()
+    # the dispatcher hands its pad_block on to the kernel
+    _, served = ivf_adc_topk(dirty_codes, dirty_slots, visit, luts, k=64,
+                             coarse=coarse, steps_per_probe=spp,
+                             mode="per_query", use_kernel=True,
+                             pad_block=pad)
+    np.testing.assert_array_equal(np.asarray(served), _live_ids(*clean))
 
 
 def test_int8_lut_quantization_bound(rng):

@@ -96,6 +96,24 @@ def test_ivf_adc_per_query_compiles(one_chip, lut_dtype, n_queries):
         ((n_queries, NPROBE), jnp.float32))
 
 
+@pytest.mark.parametrize("lut_dtype", ["float32", "int8"])
+@pytest.mark.parametrize("n_queries", [1, Q])
+def test_ivf_adc_per_query_pad_skip_compiles(one_chip, lut_dtype, n_queries):
+    # the served walk: 16 probes x 256 steps, pad steps skipped; 64 rows x
+    # 4096 steps overflow one call's SMEM visit table, so that case runs
+    # the query-chunk loop
+    spp = 256
+    compile_for(
+        one_chip,
+        lambda c, i, v, l, co: ivf.ivf_adc(c, i, v, l, co, k=K,
+                                           steps_per_probe=spp,
+                                           lut_dtype=lut_dtype,
+                                           pad_block=B - 1),
+        *BLOCKS, ((n_queries, NPROBE * spp), jnp.int32),
+        ((n_queries, M, KSUB), jnp.float32),
+        ((n_queries, NPROBE), jnp.float32))
+
+
 @pytest.mark.parametrize("lut_dtype", ["float32", "bfloat16"])
 def test_ivf_adc_blocked_compiles(one_chip, lut_dtype):
     compile_for(
