@@ -101,6 +101,17 @@ def assign_from_buckets(buckets, n_rows: int) -> np.ndarray:
     return assign
 
 
+def quarter_octave(n: int) -> int:
+    """Smallest rung >= n of the quarter-octave ladder: n itself up to 8,
+    then the multiples of 2^(k-2) within each octave (2^k, 2^(k+1)]: 10,
+    12, 14, 16, 20, 24, 28, 32, 40, ... A growing n takes O(log n)
+    distinct values, each at most 25% above n."""
+    if n <= 8:
+        return n
+    e = (n - 1).bit_length() - 3
+    return -(-n >> e) << e
+
+
 def build_block_lists(assign, n_clusters: int, blk: int = 32):
     """Host-side BLOCK-ALIGNED inverted lists for the bucket-resident kernel.
 
@@ -228,12 +239,7 @@ def build_block_schedule(visit, *, qblk: int = 8, pad_block=None):
     else:
         gid = slot = np.zeros(0, np.int64)
         n_groups = n_blocks = 0
-    G = max(1, n_groups)
-    if G > 8:  # quarter-octave bucket: next multiple of 2^e with 2^e ~ G/8
-        e = (G - 1).bit_length() - 3
-        G = -(-G >> e) << e
-    else:
-        G = 8
+    G = quarter_octave(max(8, n_groups))
     sched_block = np.full(G, fill, np.int32)
     sched_q = np.full((G, qblk), -1, np.int32)     # -1 = knockout sentinel
     sched_t = np.zeros((G, qblk), np.int32)
@@ -244,12 +250,7 @@ def build_block_schedule(visit, *, qblk: int = 8, pad_block=None):
     # run-length view: one entry per distinct block, padded on the same
     # quarter-octave ladder (of n_runs + 1, so >= 1 pad run always exists)
     n_runs = n_blocks
-    R = n_runs + 1
-    if R > 8:
-        e = (R - 1).bit_length() - 3
-        R = -(-R >> e) << e
-    else:
-        R = 8
+    R = quarter_octave(max(8, n_runs + 1))
     run_block = np.full(R, fill, np.int32)
     run_start = np.full(R, n_groups, np.int32)     # pad runs: empty tail
     run_len = np.zeros(R, np.int32)
@@ -311,8 +312,11 @@ class BlockListLayout:
     (capacity, blk, m) code payload). Row ``capacity - 1`` is the reserved
     shared all-pad block; ``block_table[c]`` lists the storage rows cluster
     c owns, in visit order, padded to the static ``steps_per_probe`` width
-    with -1. Capacities are power-of-two buckets (``mutable.row_capacity``)
-    so steady-state mutation never changes device-visible shapes —
+    with -1. Row capacities are power-of-two buckets
+    (``mutable.row_capacity``); ``steps_per_probe`` doubles up to 8 blocks,
+    then climbs the ``quarter_octave`` ladder, so a longest list one block
+    past a power of two widens every probe's visit row by at most 25%, not
+    100%. Steady-state mutation never changes device-visible shapes —
     ``shape_key`` summarizes them for the plan ledger.
 
     Invariants:
@@ -444,7 +448,8 @@ class BlockListLayout:
             self._grow_spp()
 
     def _grow_spp(self) -> None:
-        self.spp_cap *= 2
+        cap = self.spp_cap
+        self.spp_cap = 2 * cap if cap < 8 else quarter_octave(cap + 1)
         table = np.full((self.C, self.spp_cap), -1, np.int32)
         table[:, : self.block_table.shape[1]] = self.block_table
         self.block_table = table

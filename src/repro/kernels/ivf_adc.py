@@ -253,14 +253,18 @@ def ivf_adc(bucket_codes, bucket_ids, visit, luts, coarse, *, k: int,
             interpret=interpret,
         )(visit_c.reshape(qc * T), *args)
 
-    qc = max(1, min(Q, SMEM_VISIT_BYTES // (4 * T)))
-    if qc == Q:
+    n_c = -(-Q // max(1, min(Q, SMEM_VISIT_BYTES // (4 * T))))
+    if n_c == 1:
         s, i = run(*per_q)
     else:
-        # query chunks padded to a whole number (pad rows are sliced off)
-        n_c = -(-Q // qc)
-        per_q = [jnp.pad(x, ((0, n_c * qc - Q),) + ((0, 0),) * (x.ndim - 1)
-                         ).reshape((n_c, qc) + x.shape[1:]) for x in per_q]
+        # equal query chunks, padded to a whole number (pad rows are sliced
+        # off); a pad row visits only the pad block, so the skip drops it
+        qc = -(-Q // n_c)
+        fill = [0 if pad_block is None else pad_block] + [0] * (len(per_q) - 1)
+        per_q = [jnp.pad(x, ((0, n_c * qc - Q),) + ((0, 0),) * (x.ndim - 1),
+                         constant_values=f
+                         ).reshape((n_c, qc) + x.shape[1:])
+                 for x, f in zip(per_q, fill)]
         s, i = jax.lax.map(lambda xs: run(*xs), per_q)
     return s.reshape(-1, k)[:Q], i.reshape(-1, k)[:Q]
 

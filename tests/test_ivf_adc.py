@@ -164,7 +164,7 @@ def _live_ids(s, i):
 
 
 @pytest.mark.parametrize("lut_dtype", ["float32", "bfloat16", "int8"])
-@pytest.mark.parametrize("case", PAD_SKIP_CASES)
+@pytest.mark.parametrize("case", PAD_SKIP_CASES + ("ragged_m192",))
 def test_ivf_adc_pad_skip_bit_identical(rng, monkeypatch, case, lut_dtype):
     """The per-query kernel told the pad block skips its steps and returns
     bit-identical raw scores, and identical ids wherever a score is above
@@ -172,8 +172,12 @@ def test_ivf_adc_pad_skip_bit_identical(rng, monkeypatch, case, lut_dtype):
     twin; the served (normalized) answers are identical outright. k
     exceeds some queries' candidates, so knocked-out slots are compared
     too. ``smem_chunks`` shrinks the SMEM budget so the wrapper's
-    query-chunk loop (a padded last chunk included) carries the skip."""
-    codes, slots, visit, luts, coarse, spp, pad = _pad_skip_problem(rng, case)
+    query-chunk loop (a padded last chunk included) carries the skip.
+    ``ragged_m192`` is the ragged walk at the 1536-d deployment's tables:
+    192 subspaces of ksub 256, a 49,152-wide LUT row a step."""
+    m, ksub = (192, 256) if case == "ragged_m192" else (8, 32)
+    codes, slots, visit, luts, coarse, spp, pad = _pad_skip_problem(
+        rng, case, m=m, ksub=ksub)
     Q, T = visit.shape
     if case == "smem_chunks":
         monkeypatch.setattr(ivf_mod, "SMEM_VISIT_BYTES", 4 * T * 2)

@@ -373,6 +373,56 @@ def test_block_layout_compact_keeps_shapes(rng):
         assert lay.contains(i)
 
 
+@pytest.mark.parametrize("longest,spp", [(1, 1), (3, 4), (8, 8), (9, 10),
+                                         (116, 128), (128, 128), (129, 160),
+                                         (168, 192)])
+def test_block_layout_steps_per_probe_ladder(longest, spp):
+    """steps_per_probe doubles to 8 blocks, then climbs quarter octaves: a
+    longest list one block past a power of two (129) widens the visit row
+    to 160 steps, not 256."""
+    blk = 8
+    assign = np.r_[np.zeros(longest * blk - 3), np.ones(5)].astype(np.int64)
+    lay = BlockListLayout.from_assign(assign, 2, blk=blk)
+    assert lay.bcnt.max() == longest and lay.steps_per_probe == spp
+    assert lay.block_table.shape == (2, spp)
+    assert (lay.block_table[0, :longest] >= 0).all()
+    assert (lay.block_table[0, longest:] == -1).all()
+
+
+def test_block_layout_steps_per_probe_grows_one_rung_at_a_time():
+    """Appends that lengthen a list one block at a time move
+    steps_per_probe up one rung when the list outgrows it, never past 25%
+    above the longest list, through few distinct widths."""
+    lay = BlockListLayout.from_assign(np.zeros(8, np.int64), 1, blk=8)
+    seen = []
+    for i in range(1, 300):
+        lay.insert_rows(np.arange(8 * i, 8 * i + 8), np.zeros(8, np.int64))
+        longest, spp = int(lay.bcnt[0]), lay.steps_per_probe
+        assert longest <= spp <= max(8, 1.25 * longest)
+        if not seen or seen[-1] != spp:
+            seen.append(spp)
+    assert seen == [2, 4, 8, 10, 12, 14, 16, 20, 24, 28, 32, 40, 48, 56, 64,
+                    80, 96, 112, 128, 160, 192, 224, 256, 320]
+
+
+def test_steps_per_probe_width_leaves_answers_unchanged(rng):
+    """A wider visit row only adds steps on the all-pad block: the served
+    scores and ids are the same at the ladder's width and a rung up."""
+    corpus = rng.normal(size=(640, 16)).astype(np.float32)
+    q = rng.normal(size=(6, 16)).astype(np.float32)
+    db = VectorDB("ivf_pq", n_clusters=4, nprobe=3, m=4, ksub=16, refine=8,
+                  block_size=8).load(corpus)
+    longest = int(db.index.layout.bcnt.max())
+    s0, i0 = db.query(q, k=5)
+    spp0 = db.index.spp
+    assert 8 < longest <= spp0 < 2 * longest
+    db.reserve(0, spp0 - longest + 1)
+    s1, i1 = db.query(q, k=5)
+    assert db.index.spp > spp0
+    np.testing.assert_array_equal(np.asarray(i1), np.asarray(i0))
+    np.testing.assert_array_equal(np.asarray(s1), np.asarray(s0))
+
+
 def test_sharded_alloc_policy_prefers_home_shard():
     """DistributedIVFPQ routes a cluster's spilled blocks onto the shard
     already owning its slab; a full home shard falls back gracefully and a

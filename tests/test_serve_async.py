@@ -19,6 +19,8 @@ import threading
 import time
 from concurrent.futures import CancelledError
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -318,3 +320,46 @@ def test_submit_many_backpressure_cancels_stranded_requests(rng):
     assert eng.drain(timeout=30)
     eng.close()
     assert eng.latency_stats()["n"] == 8
+
+
+# ------------------------------------------------------ deployment widths
+
+@pytest.mark.parametrize("d,m", [(768, 96), (1536, 192)])
+def test_ivf_pq_deployment_widths_match_exact_scan(d, m):
+    """The MS MARCO deployments' index (cosine IVF-PQ at 8 dims a subspace,
+    ksub 256, nprobe 16, exact re-rank of 32) on a small clustered corpus,
+    served through the async front: every score is the exact cosine of its
+    id, every answer is k distinct ids best first, and recall@10 against
+    an exact f32 HIGHEST scan stays above 0.7. Rows sit 4 to a sub-centre
+    around 4 topics, so a query's top-10 spreads over many sub-centres
+    and lists (recall reads 0.79 at d=768 and 0.85 at d=1536; nprobe 4
+    reads about 0.61 at both)."""
+    rng = np.random.default_rng(d)
+    n, n_q, k = 3072, 48, 10
+    top = rng.normal(size=(4, d)).astype(np.float32)
+    sub = top[np.arange(n // 4) % 4] + 0.5 * rng.normal(
+        size=(n // 4, d)).astype(np.float32)
+    x = sub[np.arange(n) % len(sub)] + rng.normal(
+        size=(n, d)).astype(np.float32)
+    q = sub[rng.integers(0, len(sub), n_q)] + rng.normal(
+        size=(n_q, d)).astype(np.float32)
+    db = VectorDB("ivf_pq", metric="cosine", m=m, ksub=256, nprobe=16,
+                  refine=32).load(x)
+    with AsyncQueryEngine(db, max_batch=16, max_wait_ms=1.0) as eng:
+        futs = [eng.submit(row, k=k) for row in q]
+        got = [f.result(timeout=120) for f in futs]
+
+    xn = x / np.linalg.norm(x, axis=1, keepdims=True)
+    qn = q / np.linalg.norm(q, axis=1, keepdims=True)
+    exact = np.asarray(jnp.dot(jnp.asarray(qn), jnp.asarray(xn).T,
+                               precision=jax.lax.Precision.HIGHEST))
+    truth = np.argsort(-exact, axis=1)[:, :k]
+    hits = 0
+    for j, (s, i) in enumerate(got):
+        s, i = np.asarray(s), np.asarray(i)
+        assert i.shape == (k,) and len(set(i.tolist())) == k
+        assert ((i >= 0) & (i < n)).all() and (np.diff(s) <= 0).all()
+        np.testing.assert_allclose(s, np.sum(qn[j] * xn[i], axis=1),
+                                   rtol=0, atol=2e-5)
+        hits += len(set(i.tolist()) & set(truth[j].tolist()))
+    assert hits / (n_q * k) > 0.7

@@ -4,11 +4,11 @@ Interpret mode (every other kernel test) cannot see what the chip's
 compiler refuses: blocks off the (8, 128) tiling, VMEM past the scoped
 limit, scalar prefetch past the 1 MiB SMEM. These tests compile each
 kernel at the paper's widths (d=768, m=96, ksub=256, k=10; LSH at 4 x
-128-bit signatures) for one chip of
-a described v5e:2x2 topology — no chip needed — so a refused kernel fails
-here instead of on the chip. The topology is described only inside the
-module fixture: describing it loads the TPU library, which one process at
-a time may hold.
+128-bit signatures), and the per-query grid at the 1536-d deployment's
+m=192 too, for one chip of a described v5e:2x2 topology — no chip
+needed — so a refused kernel fails here instead of on the chip. The
+topology is described only inside the module fixture: describing it
+loads the TPU library, which one process at a time may hold.
 """
 import contextlib
 import importlib
@@ -111,6 +111,26 @@ def test_ivf_adc_per_query_pad_skip_compiles(one_chip, lut_dtype, n_queries):
                                            pad_block=B - 1),
         *BLOCKS, ((n_queries, NPROBE * spp), jnp.int32),
         ((n_queries, M, KSUB), jnp.float32),
+        ((n_queries, NPROBE), jnp.float32))
+
+
+# the 1536-d ada-002 deployment: 192 subspaces of 8 dims, so each grid
+# step unrolls twice the contractions over a LUT row twice as wide
+M_WIDE = 192
+
+
+@pytest.mark.parametrize("pad_skip", [False, True])
+@pytest.mark.parametrize("n_queries", [1, Q])
+def test_ivf_adc_per_query_wide_compiles(one_chip, pad_skip, n_queries):
+    spp = 256
+    compile_for(
+        one_chip,
+        lambda c, i, v, l, co: ivf.ivf_adc(
+            c, i, v, l, co, k=K, steps_per_probe=spp,
+            pad_block=B - 1 if pad_skip else None),
+        ((B, BLK, M_WIDE), jnp.uint8), ((B, BLK), jnp.int32),
+        ((n_queries, NPROBE * spp), jnp.int32),
+        ((n_queries, M_WIDE, KSUB), jnp.float32),
         ((n_queries, NPROBE), jnp.float32))
 
 
